@@ -1,7 +1,14 @@
 """The paper's own benchmark configuration: Fluidity pressure-solve matrices
 on the hybrid (node x core) mesh.  Sizes mirror Sec. 3/4 of the paper:
 the Fig. 3 matrix has 13.5M DoF / 371M nnz; Fig. 4 has 52M DoF / 1.46B nnz.
-CPU-runnable scaled-down versions are provided for measurement."""
+
+``extruded_mesh_matrix`` matches the DoF but not the density: its rows
+couple a surface point's ~6 in-layer neighbours and the layers above and
+below, ~9 nnz/row where Fluidity's P1-P1 pressure matrices have ~27.
+With seed 0, ``PAPER_SMALL`` gives 13,440,000 rows and 120,520,800 nnz
+(8.97 per row) and ``PAPER_LARGE`` 53,760,000 rows and 483,343,200 nnz
+(8.99 per row).  CPU-runnable scaled-down versions are provided for
+measurement."""
 import dataclasses
 
 from repro.configs.base import register
@@ -22,7 +29,9 @@ class CGConfig:
         return self.n_surface * self.layers
 
 
-# paper-scale matrices (dry-run / modelled benchmarks only)
+# paper-scale matrices: PAPER_SMALL (13.44M rows, 120.5M nnz) fits one v5e
+# chip (chip_smoke.py runs it cut to 16 layers); PAPER_LARGE (53.76M rows,
+# 483.3M nnz) needs the four-chip host
 PAPER_SMALL = CGConfig("fig3-13.5M", n_surface=210_000, layers=64)
 PAPER_LARGE = CGConfig("fig4-52M", n_surface=210_000, layers=256)
 # CPU-measurable versions

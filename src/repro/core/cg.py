@@ -27,6 +27,7 @@ from repro.core.spmv import SpMVPlan, make_spmv
 from repro.solvers.base import local_dot
 # compat re-export: moved into the solver subsystem
 from repro.solvers.precond import jacobi_inverse
+from repro.util import BoundJit, place_operands
 
 __all__ = ["cg_solve", "make_cg", "jacobi_inverse"]
 
@@ -102,10 +103,15 @@ def make_cg(plan: SpMVPlan, mesh, axis_names=("node", "core"),
                      transport=transport, neighbor_offsets=neighbor_offsets)
     m_inv = jacobi_inverse(plan.diag_a, plan.mask)
 
-    @jax.jit
-    def jitted(b: jax.Array, tol: jax.Array, maxiter: jax.Array):
-        return cg_solve(spmv, b, m_inv, plan.mask, tol, maxiter,
-                        maxiter_static=maxiter_static)
+    n_ops = len(spmv.operands)
+
+    def program(*args):
+        ops, (m_inv, mask, b, tol, maxiter) = args[:n_ops], args[n_ops:]
+        return cg_solve(lambda v: spmv.program(*ops, v), b, m_inv, mask,
+                        tol, maxiter, maxiter_static=maxiter_static)
+
+    jitted = BoundJit(program, spmv.operands + place_operands(
+        (m_inv, plan.mask), mesh, axis_names))
 
     def solve(b: jax.Array, tol: float = 1e-8, maxiter: int = 10_000):
         return jitted(b, jnp.asarray(tol, jnp.float32),

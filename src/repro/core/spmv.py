@@ -81,7 +81,8 @@ from repro.core.transport import (HaloTransport, get_codec,
                                   transport_stamp)
 from repro.sparse.csr import CSRMatrix
 from repro.sparse.formats import ShardFormat, get_format
-from repro.util import align_up, shard_map_compat
+from repro.util import (BoundJit, align_up, host_array, place_operands,
+                        shard_map_compat)
 
 __all__ = ["SpMVPlan", "build_spmv_plan", "make_spmv", "make_shard_body",
            "plan_shard_arrays", "plan_fields", "COMMON_FIELDS",
@@ -489,20 +490,22 @@ def build_spmv_plan(A: CSRMatrix, n_node: int, n_core: int,
                       for dst in range(n_node) for src in range(n_node)
                       if pair_counts[dst, src] > 0})
 
+    # every plan array stays on the host: the program factories place
+    # each one on their mesh once (``repro.util.place_operands``)
     plan = SpMVPlan(
         fmt_data=fmt_data,
-        send_own=jnp.asarray(send_own),
-        recv_own=jnp.asarray(halo.recv_own),
-        x_gather=jnp.asarray(x_gather),
-        diag_a=jnp.asarray(diag_a, dtype=dtype),
-        mask=jnp.asarray(mask, dtype=dtype),
+        send_own=host_array(send_own),
+        recv_own=host_array(halo.recv_own),
+        x_gather=host_array(x_gather),
+        diag_a=host_array(diag_a, dtype=dtype),
+        mask=host_array(mask, dtype=dtype),
         n=n, n_node=n_node, n_core=n_core,
         rc_pad=rc_pad, nl_pad=nl_pad, g_pad=halo.g_pad, hs=halo.h_own,
         mode=mode, format=fmt.name, transport=transport,
         wire_dtype=wire_dtype,
         n_cols=A.n_cols, cc_pad=cc_pad,
         mask_col=(None if square_default
-                  else jnp.asarray(mask_col, dtype=dtype)),
+                  else host_array(mask_col, dtype=dtype)),
     )
     stats = partition_stats(A.row_nnz, node_bounds, core_bounds_all)
     # fraction of stored slots (diag + offd, all shards) holding no real
@@ -652,6 +655,16 @@ def make_shard_body(plan: SpMVPlan,
 
     fmt = get_format(plan.format)
     if backend == "pallas":
+        if jax.default_backend() == "tpu":
+            # ROADMAP A2: Mosaic refuses every kernel as written — the ELL
+            # kernels' 1-D in-VMEM gather ("Only 2D gather is supported"),
+            # the SELL kernels' dynamic_slice, and the balanced kernel's
+            # (1, nnz_pad) block (not divisible by 8).  The chip path is
+            # backend="jnp" (XLA's gather).
+            raise NotImplementedError(
+                "backend='pallas' does not compile for TPU yet: Mosaic "
+                "refuses the SpMV kernels (1-D gather in the ELL kernels, "
+                "dynamic_slice in the SELL kernels); use backend='jnp'")
         local_matvec = fmt.matvec_pallas
     elif backend == "jnp":
         local_matvec = fmt.matvec_jnp
@@ -738,11 +751,9 @@ def make_spmv(plan: SpMVPlan, mesh: jax.sharding.Mesh,
     fn = shard_map_compat(shard_fn, mesh=mesh,
                           in_specs=(spec,) * (len(fields) + 1),
                           out_specs=spec)
-
-    @jax.jit
-    def spmv(xd: jax.Array) -> jax.Array:
-        return fn(*plan_shard_arrays(plan), *body.extra.values(), xd)
-
+    spmv = BoundJit(fn, place_operands(
+        plan_shard_arrays(plan) + tuple(body.extra.values()), mesh,
+        axis_names), mesh, spec)
     spmv.transport = body.transport
     spmv.wire_dtype = body.wire_dtype
     return spmv

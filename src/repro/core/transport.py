@@ -547,7 +547,7 @@ class HierTransport(HaloTransport):
         n_node, n_core, _, hs = recv.shape
         recv_all = np.broadcast_to(recv[:, None],
                                    (n_node, n_core, n_core, n_node, hs))
-        return {"recv_all": jnp.asarray(np.ascontiguousarray(recv_all))}
+        return {"recv_all": np.ascontiguousarray(recv_all)}
 
     def exchange(self, x_mine, F, *, state, axes, n_node, g_pad):
         node_ax, core_ax = axes
@@ -783,7 +783,7 @@ def make_exchange(plan, mesh: jax.sharding.Mesh,
     (there is no exchange to probe)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.util import shard_map_compat
+    from repro.util import BoundJit, place_operands, shard_map_compat
 
     if plan.hs == 0:
         raise ValueError("plan has no halo traffic (hs == 0): "
@@ -807,12 +807,9 @@ def make_exchange(plan, mesh: jax.sharding.Mesh,
                           in_specs=(spec,) * (3 + len(extra)),
                           out_specs=spec)
 
-    @jax.jit
-    def probe(xd: jax.Array) -> jax.Array:
-        return fn(plan.send_own, plan.recv_own,
-                  *(v for _, v in extra), xd)
-
-    return probe
+    return BoundJit(fn, place_operands(
+        (plan.send_own, plan.recv_own) + tuple(v for _, v in extra), mesh,
+        axis_names), mesh, spec)
 
 
 # --------------------------------------------------------------------- #

@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 
@@ -50,10 +49,9 @@ def main(argv=None) -> int:
                          "solution error")
     args = ap.parse_args(argv)
 
-    ndev = args.n_node * args.n_core
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={ndev}")
+    from repro.util import enable_compile_cache, use_host_devices
+    use_host_devices(args.n_node * args.n_core)
+    enable_compile_cache()
 
     import numpy as np
 
@@ -85,7 +83,10 @@ def main(argv=None) -> int:
     t_serve = time.perf_counter() - t0
     resolved = [f.result() for f in futs]
 
-    out = {"requests": args.requests, "nrhs": args.nrhs,
+    devs = svc.engine.mesh.devices.ravel()      # the mesh the engine ran on
+    out = {"device": {"platform": devs[0].platform,
+                      "kind": devs[0].device_kind, "count": int(devs.size)},
+           "requests": args.requests, "nrhs": args.nrhs,
            "solver": args.solver, "n_node": args.n_node,
            "n_core": args.n_core, "n_rows": A.n_rows,
            "served": len(results),

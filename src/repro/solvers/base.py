@@ -48,7 +48,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.solvers.precond import Preconditioner, get_precond
-from repro.util import shard_map_compat
+from repro.util import BoundJit, place_operands, shard_map_compat
 
 # NOTE: repro.core is imported lazily inside the functions below —
 # repro.core.cg itself imports this module (for local_dot/jacobi_inverse
@@ -368,8 +368,11 @@ def make_solver(plan, mesh: jax.sharding.Mesh, *,
     halo wire codec ('f32' | 'bf16' | 'int8'; ``None`` follows
     ``plan.wire_dtype`` — exposed as ``solve.wire_dtype``).
 
-    ``solve.jitted`` exposes the jitted function (``(b, tol, maxiter)``)
-    for HLO inspection — ``repro.util.while_body_collective_counts`` on it
+    The operator (plan fields, transport extras, preconditioner data and
+    the mask) is placed on ``mesh`` once, here, and passed to the program
+    as arguments.  ``solve.jitted`` (a :class:`repro.util.BoundJit`)
+    exposes the program, called and lowered as ``(b, tol, maxiter)``, for
+    HLO inspection — ``repro.util.while_body_collective_counts`` on it
     yields the per-iteration collective census.
     """
     from repro.core.spmv import (make_shard_body, plan_fields,
@@ -428,15 +431,16 @@ def make_solver(plan, mesh: jax.sharding.Mesh, *,
 
     spec = P(node_ax, core_ax)
     n_consts = len(fields) + len(pnames) + 1        # + mask
+    out_specs = (spec, P(), P())
     fn = shard_map_compat(
         shard_solve, mesh=mesh,
         in_specs=(spec,) * n_consts + (spec, P(), P()),
-        out_specs=(spec, P(), P()))
+        out_specs=out_specs)
 
-    @jax.jit
-    def jitted(b: jax.Array, tol: jax.Array, maxiter: jax.Array):
-        return fn(*plan_shard_arrays(plan), *body.extra.values(),
-                  *(pdata[k] for k in pnames), plan.mask, b, tol, maxiter)
+    jitted = BoundJit(fn, place_operands(
+        plan_shard_arrays(plan) + tuple(body.extra.values())
+        + tuple(pdata[k] for k in pnames) + (plan.mask,), mesh, axis_names),
+        mesh, out_specs)
 
     def solve(b: jax.Array, tol: float = 1e-8, maxiter: int = 10_000):
         return jitted(b, jnp.asarray(tol, jnp.float32),
@@ -485,9 +489,7 @@ def make_precond_apply(plan, mesh: jax.sharding.Mesh, *,
                           in_specs=(spec,) * (len(pnames) + 1),
                           out_specs=spec)
 
-    @jax.jit
-    def apply(rd: jax.Array) -> jax.Array:
-        return fn(*(pdata[k] for k in pnames), rd)
-
+    apply = BoundJit(fn, place_operands(
+        tuple(pdata[k] for k in pnames), mesh, axis_names), mesh, spec)
     apply.precond = pre.name
     return apply

@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.sparse.csr import CSRMatrix
+from repro.util import host_array
 
 __all__ = ["jacobi_inverse", "jacobi_inverse_np", "Preconditioner",
            "NonePrecond", "JacobiPrecond", "BlockJacobiPrecond",
@@ -238,7 +239,7 @@ class BlockJacobiPrecond(Preconditioner):
             slots = np.flatnonzero(g_of[i, c] >= 0)
             bl = g_of[i, c, slots] - blo      # bin-local row of each slot
             binv[i, c, slots[:, None], slots[None, :]] = inv[np.ix_(bl, bl)]
-        return {"binv": jnp.asarray(binv, dtype=plan.mask.dtype)}
+        return {"binv": host_array(binv, dtype=plan.mask.dtype)}
 
     def apply(self, P, r):
         binv = P["binv"]                      # (rc_pad, rc_pad)
@@ -360,7 +361,7 @@ class TwoLevelPrecond(Preconditioner):
 
         dtype = plan.mask.dtype
         ainv = self._galerkin_inverse(A, agg_of, nc)
-        pdata["ainv_c"] = jnp.asarray(
+        pdata["ainv_c"] = host_array(
             np.broadcast_to(ainv, (n_node, n_core, nc, nc)), dtype=dtype)
 
         # global coarse id -> flat slot of the core+node-gathered R output
@@ -369,14 +370,13 @@ class TwoLevelPrecond(Preconditioner):
         coarse_gather = np.zeros(nc, dtype=np.int32)
         coarse_gather[gR[ii, cc, ss]] = \
             ((ii * n_core + cc) * plan_R.rc_pad + ss).astype(np.int32)
-        pdata["coarse_gather"] = jnp.asarray(
+        pdata["coarse_gather"] = np.ascontiguousarray(
             np.broadcast_to(coarse_gather, (n_node, n_core, nc)))
 
         # per-shard map from the replicated coarse vector into P's input
         # (column-space) layout; padding slots read an appended zero
         gPc = np.asarray(layout_P["global_col_of"])
-        pdata["p_col_map"] = jnp.asarray(
-            np.where(gPc >= 0, gPc, nc).astype(np.int32))
+        pdata["p_col_map"] = np.where(gPc >= 0, gPc, nc).astype(np.int32)
 
         body_R = make_shard_body(plan_R, axis_names=axis_names,
                                  backend=backend)

@@ -59,7 +59,7 @@ from repro.runtime.fault import FaultInjector, StepGuard, Watchdog
 from repro.solvers.base import (SolverCtx, from_dist_batch, get_solver, pdot,
                                 to_dist_batch)
 from repro.solvers.precond import get_precond
-from repro.util import shard_map_compat
+from repro.util import BoundJit, place_operands, shard_map_compat
 
 __all__ = ["resilient_solve", "make_resilient", "ResilientResult",
            "SolveFailure"]
@@ -195,6 +195,11 @@ def make_resilient(plan, mesh: jax.sharding.Mesh, *,
     state_specs = tuple(spec if kinds[k] == "vector" else P()
                         for k in skeys)
 
+    # the operator shared by every program, placed on the mesh once
+    shared = place_operands(tuple(pdata[n] for n in pnames) + (plan.mask,),
+                            mesh, axis_names)
+    plan_arrays = place_operands(plan_shard_arrays(plan), mesh, axis_names)
+
     def build(tr) -> _Programs:
         body = make_shard_body(plan, axis_names=axis_names, backend=backend,
                                transport=tr,
@@ -203,6 +208,10 @@ def make_resilient(plan, mesh: jax.sharding.Mesh, *,
         fields = plan_fields(plan) + tuple(body.extra)
         n_f, n_p = len(fields), len(pnames)
         n_consts = n_f + n_p + 1                # + mask
+        operands = (plan_arrays
+                    + place_operands(tuple(body.extra.values()), mesh,
+                                     axis_names)
+                    + shared)
 
         def mk_ctx(args):
             F = {k: v[0, 0] for k, v in zip(fields, args[:n_f])}
@@ -228,13 +237,7 @@ def make_resilient(plan, mesh: jax.sharding.Mesh, *,
                 shard_fn, mesh=mesh,
                 in_specs=(spec,) * n_consts + tail_specs,
                 out_specs=out_specs)
-
-            @jax.jit
-            def run(*tail):
-                return fn(*plan_shard_arrays(plan), *body.extra.values(),
-                          *(pdata[n] for n in pnames), plan.mask, *tail)
-
-            return run
+            return BoundJit(fn, operands, mesh, out_specs)
 
         def shard_restart(*args):
             ctx, mask, (b, tol, maxiter, x, k) = mk_ctx(args)

@@ -28,7 +28,7 @@ the shard body and both solvers.  Every storage decision now lives behind a
 Two formats ship:
 
 ``ell``   row-padded ELLPACK, the historical layout: every shard stores
-          ``(rc_pad, width)`` blocks sized by the heaviest bin/row.  Cheap
+          ``(width, rc_pad)`` blocks sized by the heaviest bin/row.  Cheap
           gathers, but on skewed matrices the nnz-balanced two-level
           partition inflates ``rc_pad`` × ``width`` multiplicatively (see
           DESIGN.md §6).
@@ -57,7 +57,7 @@ import numpy as np
 
 from repro.sparse.csr import (CSRMatrix, ell_arrays_from_csr,
                               sell_arrays_from_csr)
-from repro.util import align_up
+from repro.util import align_up, host_array
 
 __all__ = ["IndexStream", "ShardFormat", "ELLFormat", "SELLFormat",
            "register_format", "get_format", "available_formats"]
@@ -167,18 +167,35 @@ def _max_width(blocks: list[CSRMatrix], align: int) -> int:
     return align_up(w, align) if w else 0
 
 
+def _ell_rows_dot(vals, cols, x):
+    """``y[r] = sum_k vals[k, r] * x[cols[k, r]]`` over a width-major ELL
+    block, one gathered column at a time: the adds run in a fixed k order
+    (every program computes the same bits, whatever else it fuses) and no
+    ``(width, rc_pad)`` gather is materialised."""
+    y = jnp.zeros(vals.shape[-1], vals.dtype)
+    for k in range(vals.shape[0]):
+        y = y + vals[k] * x[cols[k]].astype(vals.dtype)
+    return y
+
+
 # --------------------------------------------------------------------- #
 # ELL — the historical row-padded layout
 # --------------------------------------------------------------------- #
 @dataclasses.dataclass(frozen=True)
 class ELLFormat(ShardFormat):
-    """Row-padded ELLPACK blocks, ``(rc_pad, width)`` per shard."""
+    """Row-padded ELLPACK blocks, stored ``(width, rc_pad)`` per shard.
+
+    Width-major storage keeps the long row axis minor: TPU HBM tiles the
+    two minor dims by (8, 128), so a ``(rc_pad, width)`` block with width
+    ~19 would pad ~6.7x (it does not fit one v5e chip at the Fig. 3
+    size), while ``(width, rc_pad)`` pads only width up to a multiple of
+    8."""
 
     name = "ell"
     fields = ("diag_cols", "diag_vals", "offd_cols", "offd_vals")
 
     def index_streams(self):
-        # row-aligned: entry (r, k) accumulates into row r, so there is
+        # row-aligned: entry (k, r) accumulates into row r, so there is
         # no explicit rows stream to range-check
         return (IndexStream(vals="diag_vals", cols="diag_cols", x="local"),
                 IndexStream(vals="offd_vals", cols="offd_cols", x="ghost"))
@@ -189,43 +206,42 @@ class ELLFormat(ShardFormat):
         n_core = len(core_bounds[0]) - 1
         wd = _max_width(diag_nodes, width_align)
         wo = _max_width(offd_nodes, width_align)
-        diag_cols = np.zeros((n_node, n_core, rc_pad, wd), dtype=np.int32)
-        diag_vals = np.zeros((n_node, n_core, rc_pad, wd), dtype=np.float64)
-        offd_cols = np.zeros((n_node, n_core, rc_pad, wo), dtype=np.int32)
-        offd_vals = np.zeros((n_node, n_core, rc_pad, wo), dtype=np.float64)
+        diag_cols = np.zeros((n_node, n_core, wd, rc_pad), dtype=np.int32)
+        diag_vals = np.zeros((n_node, n_core, wd, rc_pad), dtype=np.float64)
+        offd_cols = np.zeros((n_node, n_core, wo, rc_pad), dtype=np.int32)
+        offd_vals = np.zeros((n_node, n_core, wo, rc_pad), dtype=np.float64)
         for i in range(n_node):
+            # (rows, width) per node, scattered width-major into the slots
             c_of, lr = c_of_all[i], slots_all[i]
             if wd:
                 dc, dv = ell_arrays_from_csr(diag_nodes[i], width=wd)
-                diag_cols[i, c_of, lr] = dc
-                diag_vals[i, c_of, lr] = dv
+                diag_cols[i, c_of, :, lr] = dc
+                diag_vals[i, c_of, :, lr] = dv
             if wo:
                 oc, ov = ell_arrays_from_csr(offd_nodes[i], width=wo)
-                offd_cols[i, c_of, lr] = oc
-                offd_vals[i, c_of, lr] = ov
-        return {"diag_cols": jnp.asarray(diag_cols),
-                "diag_vals": jnp.asarray(diag_vals, dtype=dtype),
-                "offd_cols": jnp.asarray(offd_cols),
-                "offd_vals": jnp.asarray(offd_vals, dtype=dtype)}
+                offd_cols[i, c_of, :, lr] = oc
+                offd_vals[i, c_of, :, lr] = ov
+        return {"diag_cols": host_array(diag_cols),
+                "diag_vals": host_array(diag_vals, dtype=dtype),
+                "offd_cols": host_array(offd_cols),
+                "offd_vals": host_array(offd_vals, dtype=dtype)}
 
     def nnz_stored(self, data):
         return int(data["diag_cols"].size + data["offd_cols"].size)
 
     def matvec_jnp(self, F, x_local, x_ghost, rc_pad):
-        dv = F["diag_vals"]
-        y = jnp.einsum("rk,rk->r", dv, x_local[F["diag_cols"]].astype(dv.dtype))
+        y = _ell_rows_dot(F["diag_vals"], F["diag_cols"], x_local)
         if x_ghost is None:
             return y
-        ov = F["offd_vals"]
-        return y + jnp.einsum("rk,rk->r", ov,
-                              x_ghost[F["offd_cols"]].astype(ov.dtype))
+        return y + _ell_rows_dot(F["offd_vals"], F["offd_cols"], x_ghost)
 
     def matvec_pallas(self, F, x_local, x_ghost, rc_pad):
+        # the kernels take row-major (rows, width) blocks
         from repro.kernels.ops import ell_spmv, fused_ell_spmv
         if x_ghost is None:
-            return ell_spmv(F["diag_vals"], F["diag_cols"], x_local)
-        return fused_ell_spmv(F["diag_vals"], F["diag_cols"],
-                              F["offd_vals"], F["offd_cols"],
+            return ell_spmv(F["diag_vals"].T, F["diag_cols"].T, x_local)
+        return fused_ell_spmv(F["diag_vals"].T, F["diag_cols"].T,
+                              F["offd_vals"].T, F["offd_cols"].T,
                               x_local, x_ghost)
 
 
@@ -310,12 +326,12 @@ class SELLFormat(ShardFormat):
 
         dv, dc, dr = _gather("d", d_pad)
         ov, oc, orr = _gather("o", o_pad)
-        return {"sell_dvals": jnp.asarray(dv, dtype=dtype),
-                "sell_dcols": jnp.asarray(dc),
-                "sell_drows": jnp.asarray(dr),
-                "sell_ovals": jnp.asarray(ov, dtype=dtype),
-                "sell_ocols": jnp.asarray(oc),
-                "sell_orows": jnp.asarray(orr)}
+        return {"sell_dvals": host_array(dv, dtype=dtype),
+                "sell_dcols": host_array(dc),
+                "sell_drows": host_array(dr),
+                "sell_ovals": host_array(ov, dtype=dtype),
+                "sell_ocols": host_array(oc),
+                "sell_orows": host_array(orr)}
 
     def nnz_stored(self, data):
         return int(data["sell_dvals"].size + data["sell_ovals"].size)

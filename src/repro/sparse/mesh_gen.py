@@ -99,27 +99,24 @@ def extruded_mesh_matrix(n_surface: int, layers: int, seed: int = 0,
     L = layers
     n = n2d * L
 
-    rows, cols, vals = [], [], []
-
-    def add_edge(i, j, w):
-        rows.extend([i, j])
-        cols.extend([j, i])
-        vals.extend([-w, -w])
-
     rng = np.random.default_rng(seed + 1)
-    # horizontal (in-layer) edges, replicated per layer
+    # horizontal (in-layer) edges, replicated per layer (layer-major)
     w_h = rng.uniform(0.5, 1.5, size=len(edges2d))
-    for ell in range(L):
-        base = ell
-        for (a, b), w in zip(edges2d, w_h):
-            add_edge(a * L + base, b * L + base, w)
-    # vertical (extrusion) edges within each column
-    for v in range(n2d):
-        for ell in range(L - 1):
-            add_edge(v * L + ell, v * L + ell + 1, 1.0)
-
-    return _laplacian_spd(np.asarray(rows), np.asarray(cols),
-                          np.asarray(vals, dtype=np.float64), n, shift)
+    ells = np.arange(L, dtype=np.int64)[:, None]
+    hi = (edges2d[None, :, 0] * L + ells).ravel()
+    hj = (edges2d[None, :, 1] * L + ells).ravel()
+    hw = np.tile(w_h, L)
+    # vertical (extrusion) edges within each column (column-major)
+    vi = (np.arange(n2d, dtype=np.int64)[:, None] * L
+          + np.arange(L - 1, dtype=np.int64)[None, :]).ravel()
+    i = np.concatenate([hi, vi])
+    j = np.concatenate([hj, vi + 1])
+    w = np.concatenate([hw, np.ones(vi.size)])
+    # each edge contributes (i, j) then (j, i), both weighted -w
+    rows = np.stack([i, j], axis=1).ravel()
+    cols = np.stack([j, i], axis=1).ravel()
+    vals = np.repeat(-w, 2)
+    return _laplacian_spd(rows, cols, vals, n, shift)
 
 
 def graded_extruded_mesh_matrix(n_surface: int, layers: int, seed: int = 0,

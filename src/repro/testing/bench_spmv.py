@@ -5,7 +5,6 @@ benchmarks/strong_scaling.py (Figs. 3-4).
 """
 import argparse
 import json
-import os
 import sys
 import time
 
@@ -69,10 +68,9 @@ def main() -> int:
                     help="skip the compiled-HLO collective-op census")
     args = ap.parse_args()
 
-    ndev = args.n_node * args.n_core
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={ndev}")
+    from repro.util import enable_compile_cache, use_host_devices
+    use_host_devices(args.n_node * args.n_core)
+    enable_compile_cache()
 
     import jax
     import numpy as np

@@ -35,6 +35,7 @@ import sys
 
 FORMATS = ("ell", "sell")
 PLAN_META = ("n", "n_node", "n_core", "rc_pad", "nl_pad", "g_pad", "hs")
+ELL_BLOCKS = ("diag_cols", "diag_vals", "offd_cols", "offd_vals")
 
 
 def _hash(arr) -> str:
@@ -73,9 +74,14 @@ def build_entries(n_node: int, n_core: int) -> dict:
                 format=fmt, transport=tr)
             entry: dict = {"meta": {k: int(getattr(plan, k))
                                     for k in PLAN_META}}
-            entry["plan"] = {name: _hash(arr)
-                             for name, arr in zip(plan_fields(plan),
-                                                  plan_shard_arrays(plan))}
+            # ELL blocks are stored (width, rc_pad); hash them in the
+            # fixture's row-major (rc_pad, width) orientation, so the pin
+            # is on which entry sits in which slot, not on storage order
+            entry["plan"] = {
+                name: _hash(np.swapaxes(arr, -1, -2)
+                            if name in ELL_BLOCKS else arr)
+                for name, arr in zip(plan_fields(plan),
+                                     plan_shard_arrays(plan))}
             entry["plan"]["mask"] = _hash(plan.mask)
             entry["plan"]["diag_a"] = _hash(plan.diag_a)
 

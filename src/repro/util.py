@@ -1,11 +1,15 @@
 """Small shared helpers used across core/, kernels/ and sparse/."""
 from __future__ import annotations
 
+import os
 from typing import Any, Iterator
 
 import jax
+import numpy as np
 
 __all__ = ["align_up", "shard_map_compat", "make_mesh_compat",
+           "host_array", "place_operands", "BoundJit",
+           "enable_compile_cache", "use_host_devices",
            "compiled_hlo_text", "collective_counts",
            "collective_counts_from_text", "while_body_collective_counts",
            "while_body_collective_counts_from_text", "census_split",
@@ -200,45 +204,11 @@ def while_body_collective_counts_from_text(txt: str) -> dict:
 
 
 def make_mesh_compat(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them.
-
-    Newer jax wants explicit ``axis_types=(AxisType.Auto, ...)`` for meshes
-    whose axes are used by both ``shard_map`` and auto-sharded ops; older
-    releases (e.g. 0.4.x) have neither the kwarg nor ``AxisType``.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(axis_shapes, axis_names,
-                                 axis_types=(axis_type.Auto,) * len(axis_names))
-        except TypeError:
-            pass
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def _register_optimization_barrier_batcher() -> None:
-    """Give ``lax.optimization_barrier`` a (trivial) vmap batching rule.
-
-    The barrier is semantically the identity — it only pins the schedule —
-    so batching is a pass-through.  jax (≤ 0.7 at least) ships no rule,
-    which breaks ``vmap`` over ``vector``-mode shard bodies (the batched
-    multi-RHS solver path).  Registered here, guarded, so a future jax
-    that adds its own rule wins.
-    """
-    from jax import lax
-    from jax.interpreters import batching
-
-    prim = getattr(lax, "optimization_barrier_p", None)
-    if prim is None or prim in batching.primitive_batchers:
-        return
-
-    def _batcher(args, dims, **params):
-        return prim.bind(*args, **params), dims
-
-    batching.primitive_batchers[prim] = _batcher
-
-
-_register_optimization_barrier_batcher()
+    """``jax.make_mesh`` with Auto axis types: the mesh's axes are used by
+    both ``shard_map`` and auto-sharded ops."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
 def align_up(v: int, a: int) -> int:
@@ -247,19 +217,110 @@ def align_up(v: int, a: int) -> int:
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
+    """``jax.shard_map`` with replication checking off: the SpMV/CG shard
+    bodies mix per-shard data with collectives in ways the static checker
+    cannot verify."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-    Newer jax exposes ``jax.shard_map(..., check_vma=...)``; older releases
-    only have ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-    Replication checking is disabled either way: the SpMV/CG shard bodies mix
-    per-shard data with collectives in ways the static checker cannot verify.
+
+def host_array(a, dtype=None) -> np.ndarray:
+    """``a`` as the host numpy array ``jnp.asarray(a, dtype)`` would hold
+    (same canonical dtype: int64 -> int32, float64 -> float32).  Operator
+    arrays stay on the host until a program factory places them."""
+    a = np.asarray(a)
+    return a.astype(jax.dtypes.canonicalize_dtype(
+        a.dtype if dtype is None else dtype), copy=False)
+
+
+def place_operands(arrays, mesh, axis_names=("node", "core")) -> tuple:
+    """Put each ``(n_node, n_core, d, ...)`` operator array on ``mesh``
+    once, split over its two leading dims, so no call reshards or
+    relayouts it.
+
+    The two mesh dims are stored merged into ``d``, as one
+    ``(n_node * n_core * d, ...)`` array: a device's shard is then
+    ``(d, ...)``, with no unit dims.  A ``(1, 1, d, rows)`` shard is laid
+    out by TPU with a unit dim second-minor, which is not the tiling the
+    programs read it in, so every call would copy the whole operator
+    first.  :class:`BoundJit` restores the shape inside the program."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    sharding = NamedSharding(mesh, PartitionSpec(tuple(axis_names)))
+    return tuple(jax.device_put(
+        np.reshape(a, (int(np.prod(np.shape(a)[:3])), *np.shape(a)[3:])),
+        sharding) for a in arrays)
+
+
+def _operand_shape(op) -> tuple:
+    """The ``(n_node, n_core, d, ...)`` shape of an operand stored merged
+    by :func:`place_operands`."""
+    mesh, spec = op.sharding.mesh, op.sharding.spec
+    dims = tuple(mesh.shape[name] for name in spec[0])
+    return (*dims, op.shape[0] // int(np.prod(dims)), *op.shape[1:])
+
+
+class BoundJit:
+    """``jax.jit(fn)`` with its leading operator arguments bound.
+
+    The operator reaches the program as arguments placed by
+    :func:`place_operands`, never as closure constants: the compiled
+    program does not grow with nnz.  Calls, ``lower`` and ``_cache_size``
+    take the trailing arguments only.  ``fn`` (the jitted program, taking
+    the stored operands first) and ``operands`` are exposed for lowering
+    against other (e.g. abstract) operands; ``program`` is ``fn`` before
+    binding, taking each operand as ``(n_node, n_core, ...)``.
+
+    ``out_specs`` (with ``mesh``) commits the outputs to the shard_map's
+    own output specs, so an output fed back into a program reaches the
+    executable it came from.
     """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:  # older spelling of the "don't check replication" knob
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+
+    def __init__(self, fn, operands, mesh=None, out_specs=None):
+        from jax.sharding import NamedSharding, PartitionSpec
+        self.program = fn
+        self.operands = tuple(operands)
+        shapes = tuple(_operand_shape(op) for op in self.operands)
+
+        def bound(*args):
+            ops = tuple(op.reshape(s) for op, s in zip(args, shapes))
+            return fn(*ops, *args[len(shapes):])
+
+        kw = {}
+        if out_specs is not None:
+            kw["out_shardings"] = jax.tree.map(
+                lambda s: NamedSharding(mesh, s), out_specs,
+                is_leaf=lambda s: isinstance(s, PartitionSpec))
+        self.fn = jax.jit(bound, **kw)
+
+    def __call__(self, *args):
+        return self.fn(*self.operands, *args)
+
+    def lower(self, *args):
+        return self.fn.lower(*self.operands, *args)
+
+    def _cache_size(self) -> int:
+        return self.fn._cache_size()
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is honoured as is (JAX reads
+    it itself); otherwise the cache lives at the fixed ``.jax_cache`` of
+    this checkout, so its keys stay stable from run to run.  For entry
+    points only: tests never call it."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def use_host_devices(n: int) -> None:
+    """Ask the CPU platform for ``n`` devices, so an ``n``-device mesh runs
+    on a host without accelerators.  Call before JAX touches a backend.
+
+    The option sizes the CPU client alone: on a TPU host the mesh is built
+    from the real chips, and the CPU devices go unused."""
+    jax.config.update("jax_num_cpu_devices", n)

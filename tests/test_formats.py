@@ -172,7 +172,7 @@ def test_single_node_plan_is_halo_free(fmt):
     assert plan.send_own.shape[-1] == 0
     if fmt == "ell":
         # no dead (rc_pad, 1) offd gather
-        assert plan.fmt_data["offd_cols"].shape[-1] == 0
+        assert plan.fmt_data["offd_cols"].shape[-2] == 0
     else:
         assert plan.fmt_data["sell_ovals"].shape[-1] == 0
 
@@ -192,7 +192,7 @@ def test_block_diagonal_two_node_plan_is_halo_free():
     plan, layout = build_spmv_plan(A, 2, 1, mode="task", format="ell")
     assert plan.hs == 0 and plan.g_pad == 0
     assert layout["halo"].total_ghosts == 0
-    assert plan.fmt_data["offd_cols"].shape[-1] == 0
+    assert plan.fmt_data["offd_cols"].shape[-2] == 0
 
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])

@@ -180,6 +180,8 @@ def test_two_level_iteration_scaling_regression():
             if ln.startswith("SCALING ")][0]
     data = json.loads(line[len("SCALING "):])
     bj, tl = data["block_jacobi"]["iters"], data["two_level"]["iters"]
-    assert bj == sorted(bj) and bj[-1] > bj[0]        # monotone growth
+    # block_jacobi's count at tol 1e-6 sits on an f32 residual plateau
+    # (see precond_check.scaling), so it is held to growth end to end
+    assert bj[-1] > bj[0]                             # growth
     assert max(tl) / min(tl) <= 1.3                   # flat
-    assert tl[-1] < bj[-1]                            # and cheaper
+    assert all(t < b for t, b in zip(tl, bj))         # and cheaper

@@ -35,6 +35,13 @@ def _random_rect(n_rows: int, n_cols: int, seed: int,
     rows = np.repeat(np.arange(n_rows, dtype=np.int64), per_row)
     cols = rng.integers(0, n_cols, size=rows.size)
     vals = rng.standard_normal(rows.size)
+    if n_rows == n_cols:
+        # square draws are operators the Jacobi setup must invert: give
+        # them a nonzero diagonal (build_spmv_plan rightly refuses a zero)
+        diag = np.arange(n_rows, dtype=np.int64)
+        rows = np.concatenate([rows, diag])
+        cols = np.concatenate([cols, diag])
+        vals = np.concatenate([vals, np.full(n_rows, float(per_row + 1))])
     return CSRMatrix.from_coo(rows, cols, vals, (n_rows, n_cols))
 
 

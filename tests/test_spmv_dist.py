@@ -28,6 +28,49 @@ def test_modes_agree_single_device(mode):
     np.testing.assert_allclose(y, A.matvec(x), rtol=2e-4, atol=1e-4)
 
 
+def _lowered_text(program: str, layers: int) -> str:
+    import jax
+
+    from repro.solvers import make_resilient, make_solver
+    A = extruded_mesh_matrix(30, layers, seed=0)
+    plan, _ = build_spmv_plan(A, 1, 1)
+    mesh = _mesh11()
+    tol, mx = jnp.float32(1e-5), jnp.int32(10)
+    if program == "spmv":
+        return make_spmv(plan, mesh).lower(
+            jnp.zeros(plan.x_shape)).as_text()
+    if program == "solve":
+        return make_solver(plan, mesh).jitted.lower(
+            jnp.zeros(plan.cg_shape), tol, mx).as_text()
+    rs = make_resilient(plan, mesh)
+    b = jnp.zeros((1, 1, 2, plan.rc_pad))
+    tols = jnp.full((2,), 1e-5, jnp.float32)
+    state = rs.restart(b, tols, mx, b, jnp.zeros((2,), jnp.int32))
+    jax.block_until_ready(state)
+    return rs.chunk.lower(b, tols, mx, mx, *state).as_text()
+
+
+@pytest.mark.parametrize("program", ["spmv", "solve", "chunk"])
+def test_programs_take_the_operator_as_arguments(program):
+    """The operator reaches every jitted program as arguments, never as
+    closure constants: 8x the rows and nnz leave the lowered program the
+    same size (constants would be inlined into its text)."""
+    small, large = (len(_lowered_text(program, layers))
+                    for layers in (4, 32))
+    assert large < 1.02 * small, (small, large)
+
+
+@pytest.mark.parametrize("dtype,expected", [
+    (None, np.float32), (np.dtype(np.float16), np.float16),
+    (jnp.bfloat16, jnp.bfloat16), (np.float64, np.float32)])
+def test_host_array_keeps_the_requested_dtype(dtype, expected):
+    """``host_array`` takes the dtype it is given, in canonical form (a
+    numpy dtype instance is falsy, so it must not be tested for truth)."""
+    from repro.util import host_array
+    a = host_array(np.ones((2, 3), np.float64), dtype=dtype)
+    assert isinstance(a, np.ndarray) and a.dtype == np.dtype(expected)
+
+
 def test_pallas_backend_matches_jnp():
     A = extruded_mesh_matrix(40, 4, seed=1)
     x = np.random.default_rng(1).normal(size=A.n_rows)
