@@ -7,7 +7,6 @@ paper-figure mapping):
   fig3_model/fig4_*   Figs. 3-4 — pod-scale modelled curves, paper matrices
   cg_convergence/*    Sec. 3  — CG+Jacobi protocol
   kernel/*            kernel-level padding-waste / balance comparison
-  roofline/*          §Roofline terms from the dry-run artefacts
 """
 import argparse
 import os
@@ -21,11 +20,11 @@ from common import emit  # noqa: E402
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
-                    help="comma list: ratio,scaling,cg,kernel,roofline")
+                    help="comma list: ratio,scaling,cg,kernel")
     ap.add_argument("--quick", action="store_true",
                     help="smaller matrices / fewer iters")
     args = ap.parse_args()
-    want = set((args.only or "ratio,scaling,cg,kernel,roofline").split(","))
+    want = set((args.only or "ratio,scaling,cg,kernel").split(","))
 
     n = 0
     if "kernel" in want:
@@ -48,11 +47,6 @@ def main() -> None:
     if "cg" in want:
         import cg_convergence
         r = cg_convergence.run()
-        emit(r)
-        n += len(r)
-    if "roofline" in want:
-        import roofline
-        r = roofline.run()
         emit(r)
         n += len(r)
     print(f"# {n} benchmark rows", file=sys.stderr)

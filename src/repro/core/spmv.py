@@ -79,6 +79,7 @@ from repro.core.partition import (NODE_PARTITIONS, partition_stats,
 from repro.core.transport import (HaloTransport, get_codec,
                                   resolve_transport, transport_census,
                                   transport_stamp)
+from repro.spans import span
 from repro.sparse.csr import CSRMatrix
 from repro.sparse.formats import ShardFormat, get_format
 from repro.util import (BoundJit, align_up, host_array, place_operands,
@@ -285,6 +286,17 @@ def build_spmv_plan(A: CSRMatrix, n_node: int, n_core: int,
     violation — the same checks ``repro.testing.analyze`` sweeps in CI,
     available inline for plans built outside the registry sweep.
     """
+    with span("plan.build"):
+        return _build_spmv_plan(A, n_node, n_core, mode, dtype, rows_align,
+                                width_align, node_partition, format,
+                                transport, wire_dtype, row_space, col_space,
+                                verify)
+
+
+def _build_spmv_plan(A, n_node, n_core, mode, dtype, rows_align, width_align,
+                     node_partition, format, transport, wire_dtype,
+                     row_space, col_space, verify):
+    """:func:`build_spmv_plan`'s work, inside its ``plan.build`` span."""
     if mode not in MODES:
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
     # -- up-front shape validation: fail here, not at pack/trace time ----- #
@@ -463,8 +475,9 @@ def build_spmv_plan(A: CSRMatrix, n_node: int, n_core: int,
             global_col_of[i, c_of, lr] = clo + ar
             slot_of[i, c_of, ar - ccb[c_of]] = lr
 
-    fmt_data = fmt.pack(diag_nodes, offd_nodes, core_bounds_all,
-                        c_of_all, lr_all, rc_pad, width_align, dtype)
+    with span("plan.pack"):
+        fmt_data = fmt.pack(diag_nodes, offd_nodes, core_bounds_all,
+                            c_of_all, lr_all, rc_pad, width_align, dtype)
 
     halo: HaloPlan = build_halo_plan(ghost_cols, col_node_bounds, n_core,
                                      core_bounds=col_core_bounds)
@@ -671,18 +684,22 @@ def make_shard_body(plan: SpMVPlan,
     else:
         raise ValueError(f"unknown backend {backend!r}")
 
+    @jax.named_scope("spmv")
     def body(F: dict, x_mine: jax.Array) -> jax.Array:
         if has_halo:
             # -- VecScatter analogue: owner-split halo exchange straight from
             #    this core's shard (no dependence on the intra-node gather) --
-            x_ghost = tr.exchange(x_mine, F, state=tstate, axes=axis_names,
-                                  n_node=n_node, g_pad=g_pad)
+            with jax.named_scope("halo"):
+                x_ghost = tr.exchange(x_mine, F, state=tstate,
+                                      axes=axis_names, n_node=n_node,
+                                      g_pad=g_pad)
         else:
             x_ghost = None      # halo-free plan: no exchange, no ghost phase
 
         # -- shared-memory read analogue: assemble the node-local x slice --
-        x_bins = jax.lax.all_gather(x_mine, core_ax, axis=0)  # (n_core, cc_pad)
-        x_local = x_bins.reshape(-1)[F["x_gather"]]           # (nl_pad,)
+        with jax.named_scope("assemble"):
+            x_bins = jax.lax.all_gather(x_mine, core_ax, axis=0)  # (n_core, cc_pad)
+            x_local = x_bins.reshape(-1)[F["x_gather"]]       # (nl_pad,)
 
         if mode == "vector":
             # master-only comm: no asynchronous progress — the diagonal
@@ -693,7 +710,8 @@ def make_shard_body(plan: SpMVPlan,
                 x_local, x_ghost = jax.lax.optimization_barrier(
                     (x_local, x_ghost))
 
-        return local_matvec(F, x_local, x_ghost, rc_pad)
+        with jax.named_scope("local"):
+            return local_matvec(F, x_local, x_ghost, rc_pad)
 
     body.transport = tr.name
     body.wire_dtype = tstate["wire_codec"].name

@@ -74,11 +74,13 @@ def local_dot(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.sum(a.astype(jnp.float32) * b.astype(jnp.float32), axis=-1)
 
 
+@jax.named_scope("dot")
 def pdot(axes, a: jax.Array, b: jax.Array) -> jax.Array:
     """VecDot: local partial + one tiny allreduce over ``axes``."""
     return jax.lax.psum(local_dot(a, b), axes)
 
 
+@jax.named_scope("dot")
 def pdot_stack(axes, *pairs) -> jax.Array:
     """k VecDots fused into a single stacked ``(k, nrhs)`` allreduce."""
     return jax.lax.psum(jnp.stack([local_dot(a, b) for a, b in pairs]), axes)
@@ -272,9 +274,10 @@ class Solver:
         Default: compose the loop hooks into one fused ``while_loop``.
         """
         aux, state = self.loop_setup(ctx, b, tol, maxiter)
-        state = jax.lax.while_loop(
-            lambda s: self.loop_cond(ctx, aux, s),
-            lambda s: self.loop_body(ctx, aux, s), state)
+        with jax.named_scope("krylov"):
+            state = jax.lax.while_loop(
+                lambda s: self.loop_cond(ctx, aux, s),
+                lambda s: self.loop_body(ctx, aux, s), state)
         return self.loop_finish(ctx, aux, state)
 
 
@@ -418,9 +421,14 @@ def make_solver(plan, mesh: jax.sharding.Mesh, *,
         mask, b = mask[0, 0], b[0, 0]
         if not batched:
             b = b[None]                     # (1, rc_pad)
+
+        @jax.named_scope("precond")
+        def precond(r):
+            return papply(Pd, r)
+
         ctx = SolverCtx(
             spmv=jax.vmap(lambda v: body(F, v)),
-            precond=lambda r: papply(Pd, r),
+            precond=precond,
             mask=mask, axes=axes, maxiter_static=maxiter_static,
             options=opts)
         x, iters, rel = sol.shard_loop(ctx, b * mask, tol, maxiter)

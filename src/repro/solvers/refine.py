@@ -25,15 +25,21 @@ residual to unit norm before each inner solve keeps late-cycle residuals
 
 ``make_refine`` compiles the inner solver ONCE (tol/maxiter are traced
 arguments of ``make_solver``'s program, so every cycle hits the jit
-cache) and returns a host-driven ``refine(b, tol, max_cycles)``.
+cache) and returns a host-driven ``refine(b, tol, max_cycles)``.  Each
+call is span ``refine.solve`` and each cycle ``refine.cycle``, split into
+``refine.to_device``, ``refine.inner``, ``refine.from_device`` and
+``refine.residual`` (``repro.spans``); counters ``refine.cycles`` and
+``refine.inner_iters`` add up what the calls return.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 
 import numpy as np
 
 from repro.solvers.base import make_solver
+from repro.spans import count, span
 
 __all__ = ["RefineResult", "make_refine", "refine_solve"]
 
@@ -103,37 +109,51 @@ def make_refine(plan, mesh, *, solver="cg", precond="jacobi",
                         A=A, layout=layout, options=options,
                         precond_options=precond_options)
 
+    calls = itertools.count(1)
+
     def refine(b, tol: float = 1e-7,
                max_cycles: int = 40) -> RefineResult:
         b = np.asarray(b, np.float64)
         if b.ndim != 1:
             raise ValueError("refine expects a single global (n,) RHS")
-        bnorm = max(float(np.linalg.norm(b)), 1e-300)
-        x = np.zeros_like(b)
-        r = b.copy()
-        rel = float(np.linalg.norm(r)) / bnorm
-        history: list = []
-        inner_total = 0
-        cycles = 0
-        stalled = 0
-        while rel > tol and cycles < max_cycles:
-            cycles += 1
-            rn = max(float(np.linalg.norm(r)), 1e-300)
-            # unit-norm residual: late cycles push ||r|| toward 1e-7 and
-            # below, where a raw f32 inner RHS would underflow its dots
-            rd = to_dist(np.asarray(r / rn, np.float32), layout, plan)
-            dd, it, _ = solve(rd, tol=inner_tol, maxiter=maxiter_inner)
-            inner_total += int(it)
-            d = np.asarray(from_dist(dd, layout, plan), np.float64)
-            x = x + rn * d
-            r = b - np.asarray(A.matvec(x), np.float64)
-            prev, rel = rel, float(np.linalg.norm(r)) / bnorm
-            history.append((cycles, rel))
-            # a cycle that fails to contract means the inner solve is at
-            # its floor for this system — further cycles cannot help
-            stalled = stalled + 1 if rel > 0.5 * prev else 0
-            if stalled >= 3:
-                break
+        with span("refine.solve", call=next(calls)):
+            bnorm = max(float(np.linalg.norm(b)), 1e-300)
+            x = np.zeros_like(b)
+            r = b.copy()
+            rel = float(np.linalg.norm(r)) / bnorm
+            history: list = []
+            inner_total = 0
+            cycles = 0
+            stalled = 0
+            while rel > tol and cycles < max_cycles:
+                cycles += 1
+                with span("refine.cycle", cycle=cycles):
+                    with span("refine.to_device"):
+                        rn = max(float(np.linalg.norm(r)), 1e-300)
+                        # unit-norm residual: late cycles push ||r|| toward
+                        # 1e-7 and below, where a raw f32 inner RHS would
+                        # underflow its dots
+                        rd = to_dist(np.asarray(r / rn, np.float32),
+                                     layout, plan)
+                    with span("refine.inner"):
+                        dd, it, _ = solve(rd, tol=inner_tol,
+                                          maxiter=maxiter_inner)
+                        inner_total += int(it)      # waits for the solve
+                    with span("refine.from_device"):
+                        d = np.asarray(from_dist(dd, layout, plan),
+                                       np.float64)
+                    with span("refine.residual"):
+                        x = x + rn * d
+                        r = b - np.asarray(A.matvec(x), np.float64)
+                        prev, rel = rel, float(np.linalg.norm(r)) / bnorm
+                history.append((cycles, rel))
+                # a cycle that fails to contract means the inner solve is
+                # at its floor for this system — further cycles cannot help
+                stalled = stalled + 1 if rel > 0.5 * prev else 0
+                if stalled >= 3:
+                    break
+        count("refine.cycles", cycles)
+        count("refine.inner_iters", inner_total)
         return RefineResult(
             x=x, cycles=cycles, inner_iters=inner_total, rel=rel,
             converged=bool(rel <= tol), history=history,

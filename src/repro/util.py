@@ -7,6 +7,8 @@ from typing import Any, Iterator
 import jax
 import numpy as np
 
+from repro.spans import count, span
+
 __all__ = ["align_up", "shard_map_compat", "make_mesh_compat",
            "host_array", "place_operands", "BoundJit",
            "enable_compile_cache", "use_host_devices",
@@ -243,12 +245,26 @@ def place_operands(arrays, mesh, axis_names=("node", "core")) -> tuple:
     ``(d, ...)``, with no unit dims.  A ``(1, 1, d, rows)`` shard is laid
     out by TPU with a unit dim second-minor, which is not the tiling the
     programs read it in, so every call would copy the whole operator
-    first.  :class:`BoundJit` restores the shape inside the program."""
+    first.  :class:`BoundJit` restores the shape inside the program.
+
+    Timed as span ``solver.place`` up to the end of the transfer; the
+    bytes on the devices are added to counter ``place.bytes``."""
     from jax.sharding import NamedSharding, PartitionSpec
     sharding = NamedSharding(mesh, PartitionSpec(tuple(axis_names)))
-    return tuple(jax.device_put(
-        np.reshape(a, (int(np.prod(np.shape(a)[:3])), *np.shape(a)[3:])),
-        sharding) for a in arrays)
+    with span("solver.place"):
+        placed = jax.block_until_ready(tuple(jax.device_put(
+            np.reshape(a, (int(np.prod(np.shape(a)[:3])), *np.shape(a)[3:])),
+            sharding) for a in arrays))
+    count("place.bytes", sum(_device_bytes(a) for a in placed))
+    return placed
+
+
+def _device_bytes(a) -> int:
+    """Bytes ``a`` takes on its devices, summed over them (replicas
+    included), from its sharding alone."""
+    shard = a.sharding.shard_shape(a.shape)
+    return (int(np.prod(shard)) * np.dtype(a.dtype).itemsize
+            * len(a.sharding.device_set))
 
 
 def _operand_shape(op) -> tuple:
