@@ -79,8 +79,8 @@ from repro.core.partition import (NODE_PARTITIONS, partition_stats,
 from repro.core.transport import (HaloTransport, get_codec,
                                   resolve_transport, transport_census,
                                   transport_stamp)
-from repro.spans import span
-from repro.sparse.csr import CSRMatrix
+from repro.spans import count, span
+from repro.sparse.csr import CSRMatrix, ShardBlocks
 from repro.sparse.formats import ShardFormat, get_format
 from repro.util import (BoundJit, align_up, host_array, place_operands,
                         shard_map_compat)
@@ -368,19 +368,6 @@ def _build_spmv_plan(A, n_node, n_core, mode, dtype, rows_align, width_align,
             node_partition=node_partition,
             core_partition=core_partition)
 
-    diag_nodes: list[CSRMatrix] = []
-    offd_nodes: list[CSRMatrix] = []
-    ghost_cols: list[np.ndarray] = []
-
-    for i in range(n_node):
-        lo, hi = int(node_bounds[i]), int(node_bounds[i + 1])
-        clo, chi = int(col_node_bounds[i]), int(col_node_bounds[i + 1])
-        Ai = A.row_slice(lo, hi)
-        diag_i, offd_i, ghosts = Ai.col_split(clo, chi)
-        ghost_cols.append(ghosts)
-        diag_nodes.append(diag_i)
-        offd_nodes.append(offd_i)
-
     # uniform static shapes across every (node, core) shard
     rc_pad = align_up(max(int(np.diff(cb).max()) for cb in core_bounds_all),
                       rows_align)
@@ -415,22 +402,10 @@ def _build_spmv_plan(A, n_node, n_core, mode, dtype, rows_align, width_align,
     # halo remap; the column space is the row space for square plans)
     slot_of = np.zeros((n_node, n_core, cc_pad), dtype=np.int32)
 
-    if A.n_cols == n:       # square: diag(A) exists and Jacobi needs it
-        diag_full = A.diagonal()
-        zero_diag = np.flatnonzero(diag_full == 0)
-        if zero_diag.size:
-            raise ValueError(
-                f"A has a zero or missing diagonal entry on {zero_diag.size} "
-                f"owned row(s) (first: row {int(zero_diag[0])}); the Jacobi "
-                "preconditioner 1/diag(A) would be infinite there.  Add a "
-                "diagonal shift or fix the assembly.")
-    else:                   # rectangular: no diagonal; diag_a stays ones
-        diag_full = None
-    c_of_all: list[np.ndarray] = []
     lr_all: list[np.ndarray] = []
     for i in range(n_node):
         lo = int(node_bounds[i])
-        nl = diag_nodes[i].n_rows
+        nl = int(node_bounds[i + 1]) - lo
         cb = core_bounds_all[i]
         ar = np.arange(nl, dtype=np.int64)
         c_of = np.searchsorted(cb, ar, side="right") - 1   # owning core per row
@@ -438,11 +413,8 @@ def _build_spmv_plan(A, n_node, n_core, mode, dtype, rows_align, width_align,
             lr = np.asarray(row_space["lr"][i], dtype=np.int64)  # pinned slots
         else:
             lr = fmt.slot_order(A.row_nnz[lo:lo + nl], cb)   # slot in the bin
-        c_of_all.append(c_of)
         lr_all.append(lr)
         mask[i, c_of, lr] = 1.0
-        if diag_full is not None:
-            diag_a[i, c_of, lr] = diag_full[lo:lo + nl]
         global_row_of[i, c_of, lr] = lo + ar
         if square_default:
             # column space == row space: the input-vector maps reuse the
@@ -451,11 +423,11 @@ def _build_spmv_plan(A, n_node, n_core, mode, dtype, rows_align, width_align,
             slot_of[i, c_of, ar - cb[c_of]] = lr
 
     if square_default:
-        col_c_of_all, col_lr_all = c_of_all, lr_all
+        col_lr_all = lr_all
         mask_col = mask
         global_col_of = global_row_of
     else:
-        col_c_of_all, col_lr_all = [], []
+        col_lr_all = []
         mask_col = np.zeros((n_node, n_core, cc_pad), dtype=np.float64)
         global_col_of = np.full((n_node, n_core, cc_pad), -1, dtype=np.int64)
         for i in range(n_node):
@@ -468,16 +440,35 @@ def _build_spmv_plan(A, n_node, n_core, mode, dtype, rows_align, width_align,
                 lr = np.asarray(col_space["lr"][i], dtype=np.int64)
             else:
                 lr = ar - ccb[c_of]     # identity slot order within the bin
-            col_c_of_all.append(c_of)
             col_lr_all.append(lr)
             x_gather[i, :, :ncl] = (c_of * cc_pad + lr)[None, :]
             mask_col[i, c_of, lr] = 1.0
             global_col_of[i, c_of, lr] = clo + ar
             slot_of[i, c_of, ar - ccb[c_of]] = lr
 
+    # the diag/offd split: a pass over A only where a node does not own
+    # every column (its ghost map and per-row split)
+    with span("plan.split"):
+        blocks = ShardBlocks(A, node_bounds, col_node_bounds,
+                             core_bounds_all, lr_all)
     with span("plan.pack"):
-        fmt_data = fmt.pack(diag_nodes, offd_nodes, core_bounds_all,
-                            c_of_all, lr_all, rc_pad, width_align, dtype)
+        fmt_data, fill = fmt.pack(blocks, rc_pad, width_align, dtype)
+        diag_full = blocks.walk(fill)
+    count("plan.pack.chunks", len(blocks.chunks))
+    count("plan.pack.threads", blocks.threads)
+    ghost_cols = blocks.ghost_cols
+
+    if A.n_cols == n:       # square: diag(A) exists and Jacobi needs it
+        zero_diag = np.flatnonzero(diag_full == 0)
+        if zero_diag.size:
+            raise ValueError(
+                f"A has a zero or missing diagonal entry on {zero_diag.size} "
+                f"owned row(s) (first: row {int(zero_diag[0])}); the Jacobi "
+                "preconditioner 1/diag(A) would be infinite there.  Add a "
+                "diagonal shift or fix the assembly.")
+        owned = global_row_of >= 0
+        diag_a[owned] = diag_full[global_row_of[owned]]
+    # rectangular: no diagonal; diag_a stays ones
 
     halo: HaloPlan = build_halo_plan(ghost_cols, col_node_bounds, n_core,
                                      core_bounds=col_core_bounds)

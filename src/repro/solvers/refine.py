@@ -37,6 +37,7 @@ import dataclasses
 import itertools
 
 import numpy as np
+import scipy.sparse
 
 from repro.solvers.base import make_solver
 from repro.spans import count, span
@@ -71,7 +72,7 @@ def make_refine(plan, mesh, *, solver="cg", precond="jacobi",
                 precond_options: dict | None = None):
     """Wrap a registry solver in the f64 iterative-refinement outer loop.
 
-    ``A`` (host matrix with ``matvec``) and ``layout`` (the dict
+    ``A`` (the host ``CSRMatrix``) and ``layout`` (the dict
     ``build_spmv_plan`` returned) are required: the outer loop recomputes
     r = b − Ax in f64 on the host every cycle — that exact residual is
     what lets a lossy-wire inner solve reach tolerances below its own
@@ -85,7 +86,7 @@ def make_refine(plan, mesh, *, solver="cg", precond="jacobi",
     ``.solver``/``.transport``/``.wire_dtype`` stamps).
     """
     if A is None or layout is None:
-        raise ValueError("make_refine needs A= (host matrix with matvec) "
+        raise ValueError("make_refine needs A= (the host CSRMatrix) "
                          "and layout= for the f64 outer residual recompute")
     from repro.core.spmv import from_dist, to_dist
     from repro.core.transport import get_codec, plan_wire_dtype
@@ -109,6 +110,11 @@ def make_refine(plan, mesh, *, solver="cg", precond="jacobi",
                         A=A, layout=layout, options=options,
                         precond_options=precond_options)
 
+    # the f64 residual's matvec, built once: scipy's CSR kernel sums each
+    # row in stored order, as CSRMatrix.matvec does, with no nnz-sized
+    # temporaries for the allocator to map and page in again every cycle
+    A_host = scipy.sparse.csr_matrix((A.data, A.indices, A.indptr),
+                                     shape=A.shape)
     calls = itertools.count(1)
 
     def refine(b, tol: float = 1e-7,
@@ -144,7 +150,7 @@ def make_refine(plan, mesh, *, solver="cg", precond="jacobi",
                                        np.float64)
                     with span("refine.residual"):
                         x = x + rn * d
-                        r = b - np.asarray(A.matvec(x), np.float64)
+                        r = b - np.asarray(A_host @ x, np.float64)
                         prev, rel = rel, float(np.linalg.norm(r)) / bnorm
                 history.append((cycles, rel))
                 # a cycle that fails to contract means the inner solve is

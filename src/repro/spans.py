@@ -18,7 +18,12 @@ Names in use:
 
 ==================  ====================================================
 ``plan.build``      ``core.spmv.build_spmv_plan``, the whole call
-``plan.pack``       the shard format's ``pack`` inside it
+``plan.split``      inside it: the pass that finds each node's ghost
+                    columns, where a node does not own every column
+``plan.pack``       inside it: the shard format's ``pack`` and the chunk
+                    walk that fills it; counters ``plan.pack.chunks``
+                    (chunks walked) and ``plan.pack.threads`` (the
+                    walk's pool, 1 inline)
 ``solver.place``    ``util.place_operands``, up to the transfer's end;
                     counter ``place.bytes`` (summed over devices)
 ``refine.solve``    one ``make_refine`` solve (``call``: its number);

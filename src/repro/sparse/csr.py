@@ -25,13 +25,16 @@ Three representations are used throughout the framework:
 from __future__ import annotations
 
 import dataclasses
+import os
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["CSRMatrix", "ELLMatrix", "BalancedCOO", "sell_arrays_from_csr"]
+__all__ = ["CSRMatrix", "ShardBlocks", "ShardChunk", "ELLMatrix",
+           "BalancedCOO", "sell_slices", "sell_arrays_from_csr"]
 
 
 @dataclasses.dataclass
@@ -140,46 +143,176 @@ class CSRMatrix:
             d[self.indices[hit][::-1]] = self.data[hit][::-1]
         return d
 
-    def row_slice(self, lo: int, hi: int) -> "CSRMatrix":
-        """Extract block of rows [lo, hi) (column space unchanged)."""
-        s, e = self.indptr[lo], self.indptr[hi]
-        return CSRMatrix(indptr=self.indptr[lo:hi + 1] - s,
-                         indices=self.indices[s:e].copy(),
-                         data=self.data[s:e].copy(),
-                         shape=(hi - lo, self.n_cols))
 
-    def col_split(self, lo: int, hi: int) -> tuple["CSRMatrix", "CSRMatrix", np.ndarray]:
-        """Split into (inside, outside) by column range [lo, hi).
+# ---------------------------------------------------------------------- #
+# the shard blocks of a partitioned CSR, walked in chunks
+# ---------------------------------------------------------------------- #
+#: stored entries one chunk of the shard walk holds, about: a chunk's
+#: temporaries stay ~20 MB, and a Fig. 3-size matrix gives ~700 chunks
+_CHUNK_NNZ = 1 << 19
+#: below this many stored entries the walk runs inline, with no pool
+_POOL_MIN_NNZ = 1 << 20
 
-        ``inside`` has columns renumbered to 0..hi-lo.  ``outside`` keeps a
-        *compressed* column space: its columns are renumbered into
-        0..n_ghost-1 and the returned ``ghost_cols`` array maps them back to
-        global column ids.  This mirrors PETSc's MPIAIJ diagonal /
-        off-diagonal storage with its compressed ghost column map.
-        """
-        inside_mask = (self.indices >= lo) & (self.indices < hi)
-        n = self.n_rows
-        rows = self._row_of_nnz()
 
-        def build(mask, new_indices, n_cols):
-            # boolean masking preserves the within-row entry order
-            counts = np.bincount(rows[mask], minlength=n) if self.nnz else \
-                np.zeros(n, dtype=np.int64)
-            indptr = np.zeros(n + 1, dtype=np.int64)
-            indptr[1:] = np.cumsum(counts[:n])
-            return CSRMatrix(indptr=indptr,
-                             indices=np.asarray(new_indices, dtype=np.int64),
-                             data=self.data[mask].copy(),
-                             shape=(n, n_cols))
+def _host_threads() -> int:
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:          # no affinity call on this platform
+        return os.cpu_count() or 1
 
-        inside = build(inside_mask, self.indices[inside_mask] - lo, hi - lo)
 
-        out_cols = self.indices[~inside_mask]
-        ghost_cols = np.unique(out_cols) if out_cols.size else \
-            np.zeros(0, dtype=np.int64)
-        outside = build(~inside_mask, np.searchsorted(ghost_cols, out_cols),
-                        max(1, len(ghost_cols)))
-        return inside, outside, ghost_cols
+@dataclasses.dataclass(frozen=True)
+class ShardChunk:
+    """Node-local rows ``[lo, hi)`` of node ``node``, all in core bin
+    ``core``.  ``slots[r]`` is each row's slot in the bin; ``diag`` and
+    ``offd`` are each block's ``(row_nnz, cols, vals)``: entries row after
+    row, in stored order, ``cols`` in the block's own column numbering."""
+
+    node: int
+    core: int
+    lo: int
+    hi: int
+    slots: np.ndarray
+    diag: tuple
+    offd: tuple
+
+
+class ShardBlocks:
+    """``A``'s rows as the diagonal and off-diagonal blocks of every
+    ``(node, core)`` shard, read straight from ``A`` in row chunks.
+
+    Node ``i`` owns rows ``[node_bounds[i], node_bounds[i+1])`` and columns
+    ``[col_bounds[i], col_bounds[i+1])``; ``core_bounds[i]`` splits its
+    rows into core bins and ``slots[i]`` gives each node-local row its
+    slot in its bin.  An entry in an owned column is in the diagonal
+    block, its column counted from the node's first; any other is in the
+    off-diagonal block, its column renumbered into ``ghost_cols[i]``
+    (PETSc's MPIAIJ split, with its compressed ghost map).  Entries keep
+    their stored order within a row.
+
+    Chunks hold about ``_CHUNK_NNZ`` entries, never cross a core bin, and
+    are cut by nonzeros, not rows (the paper's thread balance, on the
+    host).  From ``_POOL_MIN_NNZ`` entries on, they run on a pool of the
+    host's threads: numpy's indexing drops the GIL, and chunks own
+    disjoint rows and slots.  Nothing scales with the whole matrix but
+    the outputs; a node whose columns span all of ``A``'s has an empty
+    off-diagonal block, and its chunks copy nothing for it.
+    """
+
+    def __init__(self, A: CSRMatrix, node_bounds, col_bounds, core_bounds,
+                 slots):
+        self.A = A
+        self.node_bounds = [int(b) for b in node_bounds]
+        self.col_bounds = [int(b) for b in col_bounds]
+        self.core_bounds = [np.asarray(cb, dtype=np.int64)
+                            for cb in core_bounds]
+        self.slots = slots
+        self.n_node = len(self.node_bounds) - 1
+        self.n_core = len(self.core_bounds[0]) - 1
+        ip = A.indptr
+        self.chunks = []
+        for i in range(self.n_node):
+            base = self.node_bounds[i]
+            cb = self.core_bounds[i]
+            for c in range(self.n_core):
+                a, b = int(cb[c]), int(cb[c + 1])
+                if a == b:
+                    continue
+                rows_ip = ip[base + a:base + b + 1]
+                nnz = int(rows_ip[-1] - rows_ip[0])
+                pieces = max(1, -(-nnz // _CHUNK_NNZ))
+                cuts = a + np.searchsorted(
+                    rows_ip, rows_ip[0] + nnz * np.arange(1, pieces) / pieces)
+                edges = np.unique(np.concatenate(([a], cuts, [b])))
+                self.chunks += [(i, c, int(lo), int(hi))
+                                for lo, hi in zip(edges[:-1], edges[1:])]
+        # a node owning every column needs no split pass
+        self.covers = [self.col_bounds[i] == 0
+                       and self.col_bounds[i + 1] == A.n_cols
+                       for i in range(self.n_node)]
+        self.ghost_cols = [np.zeros(0, dtype=np.int64)] * self.n_node
+        self._offd_nnz = [None] * self.n_node
+        split = [i for i in range(self.n_node) if not self.covers[i]]
+        for i in split:
+            self._offd_nnz[i] = np.zeros(
+                self.node_bounds[i + 1] - self.node_bounds[i], dtype=np.int64)
+        found = self._map(self._split_pass,
+                          [t for t in self.chunks if t[0] in split])
+        for i in split:
+            outs = [g for t, g in found if t[0] == i and g.size]
+            if outs:
+                self.ghost_cols[i] = np.unique(np.concatenate(outs))
+
+    def row_nnz(self, i: int) -> tuple[np.ndarray, np.ndarray]:
+        """Per node-local row of node ``i``: its entries in the diagonal
+        block and in the off-diagonal block."""
+        lo, hi = self.node_bounds[i], self.node_bounds[i + 1]
+        rn = np.diff(self.A.indptr[lo:hi + 1])
+        offd = self._offd_nnz[i]
+        if offd is None:
+            return rn, np.zeros_like(rn)
+        return rn - offd, offd
+
+    def _rows(self, i, lo, hi):
+        """Node ``i``'s local rows ``[lo, hi)``: their row nnz, and their
+        entries' columns and values (views of ``A``)."""
+        ip = self.A.indptr
+        g = self.node_bounds[i]
+        s, e = int(ip[g + lo]), int(ip[g + hi])
+        return (ip[g + lo + 1:g + hi + 1] - ip[g + lo:g + hi],
+                self.A.indices[s:e], self.A.data[s:e])
+
+    def _split_pass(self, task):
+        """A chunk's off-diagonal row nnz, and its columns outside the
+        node (unique)."""
+        i, _, lo, hi = task
+        rn, cols, _ = self._rows(i, lo, hi)
+        out = np.flatnonzero((cols < self.col_bounds[i])
+                             | (cols >= self.col_bounds[i + 1]))
+        row = np.searchsorted(np.cumsum(rn), out, side="right")
+        self._offd_nnz[i][lo:hi] = np.bincount(row, minlength=hi - lo)
+        return task, np.unique(cols[out])
+
+    def _map(self, fn, tasks) -> list:
+        """``fn`` over ``tasks``, on the host's threads if ``A`` is large;
+        sets ``threads`` to the pool size (1 inline)."""
+        n = min(_host_threads(), len(tasks))
+        if self.A.nnz < _POOL_MIN_NNZ or n < 2:
+            self.threads = 1
+            return [fn(t) for t in tasks]
+        self.threads = n
+        with ThreadPoolExecutor(max_workers=n) as pool:
+            return list(pool.map(fn, tasks))
+
+    def walk(self, fill) -> np.ndarray:
+        """Run ``fill(chunk)`` on every :class:`ShardChunk`, and return
+        ``A``'s diagonal, read in the same pass: ``(n_rows,)``, 0 where a
+        row stores none, the first stored duplicate winning (as
+        :meth:`CSRMatrix.diagonal`)."""
+        diag = np.zeros(self.A.n_rows, dtype=self.A.data.dtype)
+
+        def one(task):
+            i, c, lo, hi = task
+            rn, cols, vals = self._rows(i, lo, hi)
+            g = self.node_bounds[i]
+            r = np.repeat(np.arange(g + lo, g + hi, dtype=np.int64), rn)
+            hit = np.flatnonzero(cols == r)[::-1]
+            diag[r[hit]] = vals[hit]
+            if self.covers[i]:         # its columns are A's, from 0
+                d = (rn, cols, vals)
+                o = (np.zeros_like(rn), cols[:0], vals[:0])
+            else:
+                clo = self.col_bounds[i]
+                ins = (cols >= clo) & (cols < self.col_bounds[i + 1])
+                out = ~ins
+                rn_o = self._offd_nnz[i][lo:hi]
+                d = (rn - rn_o, cols[ins] - clo, vals[ins])
+                o = (rn_o, np.searchsorted(self.ghost_cols[i], cols[out]),
+                     vals[out])
+            fill(ShardChunk(i, c, lo, hi, self.slots[i][lo:hi], d, o))
+
+        self._map(one, self.chunks)
+        return diag
 
 
 # ---------------------------------------------------------------------- #
@@ -203,6 +336,26 @@ def ell_arrays_from_csr(m: CSRMatrix, width: int | None = None,
         cols[r, k] = m.indices
         vals[r, k] = m.data
     return cols, vals
+
+
+def sell_slices(row_nnz: np.ndarray, slots: np.ndarray, slice_height: int
+                ) -> tuple[np.ndarray, np.ndarray]:
+    """SELL-C slice layout of rows with ``row_nnz`` entries at ``slots``:
+    each slice's width (the widest of its ``slice_height`` slots; ``(1,)``
+    zeros for no rows) and first storage position (``(n_slices + 1,)``,
+    the last entry the total size).  Slot ``q``'s ``k``-th entry is stored
+    at ``starts[s] + (q - s * C) * width[s] + k``, ``s = q // C``."""
+    nr = len(row_nnz)
+    C = int(slice_height)
+    n_slices = -(-nr // C)
+    w = np.zeros(max(n_slices, 1), dtype=np.int64)
+    if nr:
+        np.maximum.at(w, np.asarray(slots, dtype=np.int64) // C, row_nnz)
+    starts = np.zeros(n_slices + 1, dtype=np.int64)
+    starts[1:] = np.cumsum(C * w[:n_slices])
+    return w, starts
+
+
 def sell_arrays_from_csr(m: CSRMatrix, slots: np.ndarray, slice_height: int
                          ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Host-side sliced-ELL (SELL-C) packing with a caller-provided row
@@ -219,16 +372,10 @@ def sell_arrays_from_csr(m: CSRMatrix, slots: np.ndarray, slice_height: int
     ``rows`` holds the slot each entry accumulates into; padding entries have
     ``vals == 0`` (and ``cols == rows == 0``), so they contribute nothing.
     """
-    nr = m.n_rows
     C = int(slice_height)
     rn = m.row_nnz
-    n_slices = -(-max(nr, 0) // C) if nr else 0
-    w = np.zeros(max(n_slices, 1), dtype=np.int64)
     slots = np.asarray(slots, dtype=np.int64)
-    if nr:
-        np.maximum.at(w, slots // C, rn)
-    starts = np.zeros(n_slices + 1, dtype=np.int64)
-    starts[1:] = np.cumsum(C * w[:n_slices])
+    w, starts = sell_slices(rn, slots, C)
     size = int(starts[-1])
     vals = np.zeros(size, dtype=np.float64)
     cols = np.zeros(size, dtype=np.int32)

@@ -9,9 +9,10 @@ the shard body and both solvers.  Every storage decision now lives behind a
     permutation is folded into ``x_gather``/``global_row_of``/``mask``/the
     halo plan by ``build_spmv_plan``, so ``to_dist``/``from_dist`` and the
     exchange machinery need no per-format special cases);
-  * the **host-side packing** of the per-(node, core) diag/offd CSR blocks
+  * the **host-side packing** of the per-(node, core) diag/offd blocks
     into device arrays (``pack`` — one dict entry per name in ``fields``,
-    every array leading with ``(n_node, n_core)`` shard dims);
+    every array leading with ``(n_node, n_core)`` shard dims, filled chunk
+    by chunk straight from the CSR, ``repro.sparse.csr.ShardBlocks``);
   * the **local two-phase matvec** in both backends (``matvec_jnp`` /
     ``matvec_pallas``), called from inside the ``shard_map`` body with the
     assembled ``x_local`` slice and the exchanged ``x_ghost`` buffer
@@ -50,14 +51,14 @@ matvec dispatch only needs the name.
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.sparse.csr import (CSRMatrix, ell_arrays_from_csr,
-                              sell_arrays_from_csr)
-from repro.util import align_up, host_array
+from repro.sparse.csr import ShardBlocks, sell_slices
+from repro.util import align_up
 
 __all__ = ["IndexStream", "ShardFormat", "ELLFormat", "SELLFormat",
            "register_format", "get_format", "available_formats"]
@@ -117,16 +118,15 @@ class ShardFormat:
         return ar - cb[c_of]
 
     # -- host-side packing --------------------------------------------- #
-    def pack(self, diag_nodes: list[CSRMatrix], offd_nodes: list[CSRMatrix],
-             core_bounds: list[np.ndarray], c_of_all: list[np.ndarray],
-             slots_all: list[np.ndarray], rc_pad: int, width_align: int,
-             dtype) -> dict[str, jax.Array]:
-        """Pack per-node diag/offd CSR blocks into the device arrays.
-
-        ``c_of_all[i]``/``slots_all[i]``: owning core and bin slot of every
-        node-local row of node ``i``.  Returns one ``(n_node, n_core, ...)``
-        array per name in ``fields``.
-        """
+    def pack(self, blocks: ShardBlocks, rc_pad: int, width_align: int,
+             dtype) -> tuple[dict[str, np.ndarray], Callable]:
+        """Allocate the device arrays of ``blocks``' shards and return them
+        with ``fill(chunk)``, which writes one ``ShardChunk``'s entries
+        into them.  One ``(n_node, n_core, ...)`` array per name in
+        ``fields``, values in ``dtype`` (canonical, as ``host_array``
+        gives).  The plan builder runs ``fill`` over ``blocks.walk``,
+        maybe on several threads at once: chunks own disjoint rows, and
+        ``fill`` writes only their slots."""
         raise NotImplementedError
 
     # -- static contract ----------------------------------------------- #
@@ -160,11 +160,16 @@ class ShardFormat:
         raise NotImplementedError
 
 
-def _max_width(blocks: list[CSRMatrix], align: int) -> int:
-    """Largest row nnz over the blocks, aligned — 0 when every block is
+def _max_width(row_nnz: list[np.ndarray], align: int) -> int:
+    """Largest row nnz over the nodes, aligned — 0 when every block is
     empty (no dead ``(rc_pad, 1)`` gather for halo-free matrices)."""
-    w = max((int(b.row_nnz.max()) for b in blocks if b.nnz), default=0)
+    w = max((int(rn.max()) for rn in row_nnz if rn.size), default=0)
     return align_up(w, align) if w else 0
+
+
+def _values_dtype(dtype) -> np.dtype:
+    """The value dtype ``host_array`` gives for ``dtype``."""
+    return np.dtype(jax.dtypes.canonicalize_dtype(dtype))
 
 
 def _ell_rows_dot(vals, cols, x):
@@ -200,31 +205,32 @@ class ELLFormat(ShardFormat):
         return (IndexStream(vals="diag_vals", cols="diag_cols", x="local"),
                 IndexStream(vals="offd_vals", cols="offd_cols", x="ghost"))
 
-    def pack(self, diag_nodes, offd_nodes, core_bounds, c_of_all, slots_all,
-             rc_pad, width_align, dtype):
-        n_node = len(diag_nodes)
-        n_core = len(core_bounds[0]) - 1
-        wd = _max_width(diag_nodes, width_align)
-        wo = _max_width(offd_nodes, width_align)
-        diag_cols = np.zeros((n_node, n_core, wd, rc_pad), dtype=np.int32)
-        diag_vals = np.zeros((n_node, n_core, wd, rc_pad), dtype=np.float64)
-        offd_cols = np.zeros((n_node, n_core, wo, rc_pad), dtype=np.int32)
-        offd_vals = np.zeros((n_node, n_core, wo, rc_pad), dtype=np.float64)
-        for i in range(n_node):
-            # (rows, width) per node, scattered width-major into the slots
-            c_of, lr = c_of_all[i], slots_all[i]
-            if wd:
-                dc, dv = ell_arrays_from_csr(diag_nodes[i], width=wd)
-                diag_cols[i, c_of, :, lr] = dc
-                diag_vals[i, c_of, :, lr] = dv
-            if wo:
-                oc, ov = ell_arrays_from_csr(offd_nodes[i], width=wo)
-                offd_cols[i, c_of, :, lr] = oc
-                offd_vals[i, c_of, :, lr] = ov
-        return {"diag_cols": host_array(diag_cols),
-                "diag_vals": host_array(diag_vals, dtype=dtype),
-                "offd_cols": host_array(offd_cols),
-                "offd_vals": host_array(offd_vals, dtype=dtype)}
+    def pack(self, blocks, rc_pad, width_align, dtype):
+        shard = (blocks.n_node, blocks.n_core)
+        parts = [blocks.row_nnz(i) for i in range(blocks.n_node)]
+        out, targets = {}, []
+        for p, (cname, vname) in enumerate((("diag_cols", "diag_vals"),
+                                            ("offd_cols", "offd_vals"))):
+            w = _max_width([rn[p] for rn in parts], width_align)
+            out[cname] = np.zeros(shard + (w, rc_pad), dtype=np.int32)
+            out[vname] = np.zeros(shard + (w, rc_pad),
+                                  dtype=_values_dtype(dtype))
+            targets.append((out[cname], out[vname]))
+
+        def fill(ch):
+            for (cols_out, vals_out), (rn, cols, vals) in zip(
+                    targets, (ch.diag, ch.offd)):
+                if not len(cols):
+                    continue
+                # entry k of slot q sits at k * rc_pad + q of the shard's
+                # (width, rc_pad) block
+                first = np.cumsum(rn) - rn
+                pos = (np.arange(len(cols), dtype=np.int64) * rc_pad
+                       + np.repeat(ch.slots - first * rc_pad, rn))
+                cols_out[ch.node, ch.core].reshape(-1)[pos] = cols
+                vals_out[ch.node, ch.core].reshape(-1)[pos] = vals
+
+        return out, fill
 
     def nnz_stored(self, data):
         return int(data["diag_cols"].size + data["offd_cols"].size)
@@ -291,47 +297,47 @@ class SELLFormat(ShardFormat):
             lr[lo:hi] = s
         return lr
 
-    def pack(self, diag_nodes, offd_nodes, core_bounds, c_of_all, slots_all,
-             rc_pad, width_align, dtype):
-        n_node = len(diag_nodes)
-        n_core = len(core_bounds[0]) - 1
-        parts: dict[tuple[int, int, str], tuple] = {}
-        d_sizes, o_sizes = [0], [0]
-        for i in range(n_node):
-            cb = core_bounds[i]
-            for c in range(n_core):
+    def pack(self, blocks, rc_pad, width_align, dtype):
+        C = self.slice_height
+        shard = (blocks.n_node, blocks.n_core)
+        slices, sizes = {}, ([0], [0])
+        for i in range(blocks.n_node):
+            cb = blocks.core_bounds[i]
+            parts = blocks.row_nnz(i)
+            for c in range(blocks.n_core):
                 lo, hi = int(cb[c]), int(cb[c + 1])
-                sl = slots_all[i][lo:hi]
-                d = sell_arrays_from_csr(diag_nodes[i].row_slice(lo, hi),
-                                         sl, self.slice_height)
-                o = sell_arrays_from_csr(offd_nodes[i].row_slice(lo, hi),
-                                         sl, self.slice_height)
-                parts[(i, c, "d")], parts[(i, c, "o")] = d, o
-                d_sizes.append(len(d[0]))
-                o_sizes.append(len(o[0]))
-        d_pad = align_up(max(d_sizes), self.nnz_align) if max(d_sizes) else 0
-        o_pad = align_up(max(o_sizes), self.nnz_align) if max(o_sizes) else 0
+                for p in (0, 1):
+                    w, starts = sell_slices(parts[p][lo:hi],
+                                            blocks.slots[i][lo:hi], C)
+                    slices[i, c, p] = w, starts
+                    sizes[p].append(int(starts[-1]))
+        out, targets = {}, []
+        for p, names in enumerate((("sell_dvals", "sell_dcols", "sell_drows"),
+                                   ("sell_ovals", "sell_ocols", "sell_orows"))):
+            pad = (align_up(max(sizes[p]), self.nnz_align)
+                   if max(sizes[p]) else 0)
+            out[names[0]] = np.zeros(shard + (pad,),
+                                     dtype=_values_dtype(dtype))
+            out[names[1]] = np.zeros(shard + (pad,), dtype=np.int32)
+            out[names[2]] = np.zeros(shard + (pad,), dtype=np.int32)
+            targets.append(tuple(out[k] for k in names))
 
-        def _gather(key, pad):
-            vals = np.zeros((n_node, n_core, pad), dtype=np.float64)
-            cols = np.zeros((n_node, n_core, pad), dtype=np.int32)
-            rows = np.zeros((n_node, n_core, pad), dtype=np.int32)
-            for i in range(n_node):
-                for c in range(n_core):
-                    v, cc, rr = parts[(i, c, key)]
-                    vals[i, c, :len(v)] = v
-                    cols[i, c, :len(v)] = cc
-                    rows[i, c, :len(v)] = rr
-            return vals, cols, rows
+        def fill(ch):
+            q = ch.slots
+            for p, ((vals_out, cols_out, rows_out), (rn, cols, vals)) in \
+                    enumerate(zip(targets, (ch.diag, ch.offd))):
+                if not len(cols):
+                    continue
+                w, starts = slices[ch.node, ch.core, p]
+                s = q // C
+                first = np.cumsum(rn) - rn
+                pos = np.arange(len(cols), dtype=np.int64) + np.repeat(
+                    starts[s] + (q - s * C) * w[s] - first, rn)
+                vals_out[ch.node, ch.core][pos] = vals
+                cols_out[ch.node, ch.core][pos] = cols
+                rows_out[ch.node, ch.core][pos] = np.repeat(q, rn)
 
-        dv, dc, dr = _gather("d", d_pad)
-        ov, oc, orr = _gather("o", o_pad)
-        return {"sell_dvals": host_array(dv, dtype=dtype),
-                "sell_dcols": host_array(dc),
-                "sell_drows": host_array(dr),
-                "sell_ovals": host_array(ov, dtype=dtype),
-                "sell_ocols": host_array(oc),
-                "sell_orows": host_array(orr)}
+        return out, fill
 
     def nnz_stored(self, data):
         return int(data["sell_dvals"].size + data["sell_ovals"].size)

@@ -44,6 +44,7 @@ def test_refine_single_device_below_f32_floor():
     true_rel = float(np.linalg.norm(b - A.matvec(res.x))
                      / np.linalg.norm(b))
     assert true_rel <= 1e-7
+    assert res.rel == true_rel      # the loop's residual, bit for bit
     assert res.cycles >= 2                    # one f32 solve can't get here
     assert res.history[-1] == (res.cycles, res.rel)
     assert res.solver == "cg" and res.wire_dtype == "f32"

@@ -4,7 +4,8 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.sparse import CSRMatrix, extruded_mesh_matrix, random_spd_matrix
-from repro.sparse.csr import ELLMatrix
+from repro.sparse import csr as csr_mod
+from repro.sparse.csr import ELLMatrix, ShardBlocks
 
 
 def test_csr_roundtrip_dense():
@@ -22,24 +23,53 @@ def test_csr_matvec_matches_dense():
     np.testing.assert_allclose(m.matvec(x), d @ x, atol=1e-12)
 
 
-def test_row_slice():
+def _walk(A, node_bounds, core_bounds):
+    """Walk ``A``'s shard blocks, square column partition = row partition;
+    returns the blocks, every chunk, and the walk's diagonal."""
+    core_bounds = [np.asarray(cb) for cb in core_bounds]
+    rows = [np.arange(cb[-1]) for cb in core_bounds]
+    slots = [r - cb[np.searchsorted(cb, r, side="right") - 1]
+             for r, cb in zip(rows, core_bounds)]
+    blocks = ShardBlocks(A, node_bounds, node_bounds, core_bounds, slots)
+    chunks = []
+    diag = blocks.walk(chunks.append)
+    return blocks, chunks, diag
+
+
+def test_row_slice(monkeypatch):
+    """The walk's chunks tile every node's rows once, inside core bins,
+    cut by nonzeros; the diagonal read on the way is ``A.diagonal()``."""
+    monkeypatch.setattr(csr_mod, "_CHUNK_NNZ", 40)
     A = random_spd_matrix(50, seed=0)
-    B = A.row_slice(10, 30)
-    np.testing.assert_allclose(B.to_dense(), A.to_dense()[10:30])
+    blocks, chunks, diag = _walk(A, [0, 10, 30, 50],
+                                 [[0, 4, 10], [0, 12, 20], [0, 7, 20]])
+    np.testing.assert_array_equal(diag, A.diagonal())
+    for i, cbs in enumerate(([0, 4, 10], [0, 12, 20], [0, 7, 20])):
+        mine = sorted((ch.lo, ch.hi, ch.core) for ch in chunks
+                      if ch.node == i)
+        assert [lo for lo, _, _ in mine][0] == 0
+        assert all(a[1] == b[0] for a, b in zip(mine, mine[1:]))
+        assert mine[-1][1] == cbs[-1]
+        for lo, hi, c in mine:
+            assert cbs[c] <= lo < hi <= cbs[c + 1]
+    assert len(chunks) > 6          # bounds inside the core bins
 
 
-def test_col_split_reassembles():
+def test_col_split_reassembles(monkeypatch):
     """diag + offdiag (through the ghost map) must reproduce the block."""
+    monkeypatch.setattr(csr_mod, "_CHUNK_NNZ", 25)
     A = random_spd_matrix(60, seed=2)
     lo, hi = 20, 40
-    Ai = A.row_slice(lo, hi)
-    diag, offd, ghosts = Ai.col_split(lo, hi)
+    blocks, chunks, _ = _walk(A, [0, lo, hi, 60], [[0, 20], [0, 20],
+                                                   [0, 20]])
+    ghosts = blocks.ghost_cols[1]
     dense = np.zeros((hi - lo, A.n_cols))
-    dense[:, lo:hi] = diag.to_dense()
-    od = offd.to_dense()
-    for g_local, g_global in enumerate(ghosts):
-        dense[:, g_global] += od[:, g_local]
-    np.testing.assert_allclose(dense, Ai.to_dense())
+    for ch in (ch for ch in chunks if ch.node == 1):
+        for (rn, cols, vals), to_global in ((ch.diag, lambda c: c + lo),
+                                            (ch.offd, lambda c: ghosts[c])):
+            rows = np.repeat(np.arange(ch.lo, ch.hi), rn)
+            np.add.at(dense, (rows, to_global(cols)), vals)
+    np.testing.assert_allclose(dense, A.to_dense()[lo:hi])
     assert np.all(ghosts < A.n_cols)
     assert np.all((ghosts < lo) | (ghosts >= hi))
 
