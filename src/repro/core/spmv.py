@@ -470,29 +470,30 @@ def _build_spmv_plan(A, n_node, n_core, mode, dtype, rows_align, width_align,
         diag_a[owned] = diag_full[global_row_of[owned]]
     # rectangular: no diagonal; diag_a stays ones
 
-    halo: HaloPlan = build_halo_plan(ghost_cols, col_node_bounds, n_core,
-                                     core_bounds=col_core_bounds)
-    # halo send indices are bin-local row ids; route them through the
-    # format's slot assignment (identity for ELL) so the exchange reads the
-    # permuted vector shards correctly with no format special case
-    send_own = slot_of[np.arange(n_node)[:, None, None, None],
-                       np.arange(n_core)[None, :, None, None],
-                       halo.send_own]
+    with span("plan.halo"):
+        halo: HaloPlan = build_halo_plan(ghost_cols, col_node_bounds, n_core,
+                                         core_bounds=col_core_bounds)
+        # halo send indices are bin-local row ids; route them through the
+        # format's slot assignment (identity for ELL) so the exchange reads
+        # the permuted vector shards correctly with no format special case
+        send_own = slot_of[np.arange(n_node)[:, None, None, None],
+                           np.arange(n_core)[None, :, None, None],
+                           halo.send_own]
 
-    # neighbour structure (diagnostics; the ring/pairwise transports derive
-    # the same structure from the plan's own recv table): which
-    # (dst - src) mod n offsets actually carry halo traffic.  Contiguous
-    # partitions of banded (extrusion-ordered) matrices touch only a few
-    # neighbours.
-    pair_counts = np.zeros((n_node, n_node), dtype=np.int64)
-    for dst in range(n_node):
-        g = np.asarray(ghost_cols[dst], dtype=np.int64)
-        if g.size:
-            owner = np.searchsorted(col_node_bounds, g, side="right") - 1
-            pair_counts[dst] = np.bincount(owner, minlength=n_node)
-    offsets = sorted({int((dst - src) % n_node)
-                      for dst in range(n_node) for src in range(n_node)
-                      if pair_counts[dst, src] > 0})
+        # neighbour structure (diagnostics; the ring/pairwise transports
+        # derive the same structure from the plan's own recv table): which
+        # (dst - src) mod n offsets actually carry halo traffic.
+        # Contiguous partitions of banded (extrusion-ordered) matrices
+        # touch only a few neighbours.
+        pair_counts = np.zeros((n_node, n_node), dtype=np.int64)
+        for dst in range(n_node):
+            g = np.asarray(ghost_cols[dst], dtype=np.int64)
+            if g.size:
+                owner = np.searchsorted(col_node_bounds, g, side="right") - 1
+                pair_counts[dst] = np.bincount(owner, minlength=n_node)
+        offsets = sorted({int((dst - src) % n_node)
+                          for dst in range(n_node) for src in range(n_node)
+                          if pair_counts[dst, src] > 0})
 
     # every plan array stays on the host: the program factories place
     # each one on their mesh once (``repro.util.place_operands``)
@@ -511,6 +512,15 @@ def _build_spmv_plan(A, n_node, n_core, mode, dtype, rows_align, width_align,
         mask_col=(None if square_default
                   else host_array(mask_col, dtype=dtype)),
     )
+    # what one SpMV moves between devices, summed over them: the node
+    # exchange as the stamped transport predicts it (none stamped yet under
+    # "auto"), and the core-axis all_gather of the x bins
+    census = transport_census(plan)
+    if transport != "auto":
+        count("halo.wire_bytes", census[transport]["wire_bytes"])
+    count("halo.ghosts", halo.total_ghosts)
+    count("spmv.gather_bytes", n_node * n_core * (n_core - 1) * cc_pad
+          * np.dtype(dtype).itemsize)
     stats = partition_stats(A.row_nnz, node_bounds, core_bounds_all)
     # fraction of stored slots (diag + offd, all shards) holding no real
     # entry — computed by the format, since only it knows what it pads
@@ -525,7 +535,7 @@ def _build_spmv_plan(A, n_node, n_core, mode, dtype, rows_align, width_align,
         "halo": halo,
         "neighbor_offsets": offsets,
         "pair_counts": pair_counts,
-        "transport_census": transport_census(plan),
+        "transport_census": census,
         "stats": stats,
         # partition descriptors another plan can pin its spaces to
         # (restriction / prolongation locking onto this plan's layout)

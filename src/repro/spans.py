@@ -24,6 +24,19 @@ Names in use:
                     walk that fills it; counters ``plan.pack.chunks``
                     (chunks walked) and ``plan.pack.threads`` (the
                     walk's pool, 1 inline)
+``plan.halo``       inside it: the halo plan (send and receive tables
+                    from each node's ghost columns, routed through the
+                    format's slot maps) and the neighbour structure
+``halo.wire_bytes`` counter, once a plan build: the bytes one SpMV's node
+                    exchange puts on the wire, summed over devices, as
+                    the stamped transport's ``predicted_cost`` gives them
+                    for its wire codec (no count where the transport is
+                    ``"auto"``, chosen later)
+``halo.ghosts``     counter, once a plan build: the ghost values one
+                    SpMV receives, summed over nodes
+``spmv.gather_bytes``  counter, once a plan build: the bytes one SpMV's
+                    core-axis ``all_gather`` of the x bins receives,
+                    summed over devices (0 on one core)
 ``solver.place``    ``util.place_operands``, up to the transfer's end;
                     counter ``place.bytes`` (summed over devices)
 ``refine.solve``    one ``make_refine`` solve (``call``: its number);

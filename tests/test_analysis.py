@@ -98,10 +98,15 @@ def test_verify_hook_accepts_clean_plan():
 def test_duplicate_ghost_writer_flagged(pick):
     _, plan, layout = _case("sell")
     recv = np.asarray(plan.recv_own).copy()
-    real = np.argwhere(recv < plan.g_pad)
+    real = np.argwhere(recv < plan.g_pad)    # rows (dst, core, src, k)
+    # writers are counted per destination node: the second writer is
+    # drawn from a's own node, among the nodes that receive two or more
+    nodes, n_real = np.unique(real[:, 0], return_counts=True)
+    real = real[np.isin(real[:, 0], nodes[n_real >= 2])]
     assert len(real) >= 2
-    a = real[pick % (len(real) - 1)]
-    b = real[(pick % (len(real) - 1)) + 1]
+    a = real[pick % len(real)]
+    same = real[(real[:, 0] == a[0]) & (real != a).any(axis=1)]
+    b = same[pick % len(same)]
     recv[tuple(b)] = recv[tuple(a)]          # second writer for a's slot
     mut = dataclasses.replace(plan, recv_own=jnp.asarray(recv))
     assert "P_GHOST_MULTI_WRITER" in _codes(check_plan(mut, layout))

@@ -6,10 +6,13 @@ Single-device checks run in-process; the 8-device acceptance runs
 (``repro.testing.refine_check``) spawn a fresh interpreter and hold every
 solver x wire-dtype combination against the numpy f64 host-CG oracle.
 """
+import json
+import os
+
 import numpy as np
 import pytest
 
-from conftest import run_subprocess
+from conftest import REPO, run_subprocess
 from repro.core import build_spmv_plan
 from repro.solvers import RefineResult, make_refine, refine_solve
 from repro.sparse import graded_extruded_mesh_matrix
@@ -70,6 +73,59 @@ def test_multidevice_refine_cg_lossy_wire_vs_f64_oracle():
     assert r.returncode == 0, r.stdout + r.stderr
     assert "OK" in r.stdout and "BAD" not in r.stdout
     assert "ROLLBACKS 0" in r.stdout, r.stdout
+
+
+#: the 2x2 refinement case, run in a process of 8 host devices: the P1
+#: unit-cube Poisson operator of the benchmark's generator at 1000 DoF,
+#: unpreconditioned CG under the float64 refinement, three seeded
+#: right-hand sides, each answer against scipy's direct float64 solve
+MESH_REFINE_CASE = """
+import importlib.util, json, sys
+import jax, numpy as np, scipy.sparse, scipy.sparse.linalg
+from repro.core import build_spmv_plan
+from repro.solvers import make_refine
+from repro.sparse import CSRMatrix
+spec = importlib.util.spec_from_file_location("unit_cube_p1", sys.argv[1])
+gen = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(gen)
+indptr, indices, data = gen.build(target_dofs=1000)
+n = len(indptr) - 1
+A = CSRMatrix(indptr, indices, data, shape=(n, n))
+plan, layout = build_spmv_plan(A, 2, 2)
+mesh = jax.make_mesh((2, 2), ("node", "core"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2,
+                     devices=jax.devices()[:4])
+refine = make_refine(plan, mesh, solver="cg", precond="none", A=A,
+                     layout=layout, maxiter_inner=10000, maxiter_static=10000)
+A64 = scipy.sparse.csr_matrix((data, indices, indptr), shape=(n, n))
+out = []
+for seed in (1, 2, 3):
+    b = np.random.default_rng(seed).standard_normal(n)
+    res = refine(b, tol=1e-7)
+    x_ref = scipy.sparse.linalg.spsolve(A64.tocsc(), b)
+    out.append({"true_rel": float(np.linalg.norm(b - A64 @ res.x)
+                                  / np.linalg.norm(b)),
+                "x_rel": float(np.linalg.norm(res.x - x_ref)
+                               / np.linalg.norm(x_ref)),
+                "cycles": res.cycles, "devices": len(jax.devices())})
+print(json.dumps({"n": n, "hs": plan.hs, "answers": out}))
+"""
+
+
+def test_mesh_refine_poisson_cube_vs_f64_reference():
+    """``make_refine`` over unpreconditioned CG on a 2x2 mesh, as the
+    benchmark's four-chip Poisson cell drives it: every answer meets a
+    float64 true residual of 1e-7 and agrees with the float64 direct
+    solution to 1e-6 relative."""
+    gen = os.path.join(REPO, "bench", "generators", "unit_cube_p1.py")
+    r = run_subprocess(["-c", MESH_REFINE_CASE, gen], device_count=8)
+    assert r.returncode == 0, r.stdout + r.stderr
+    res = json.loads(r.stdout.strip().splitlines()[-1])
+    assert res["n"] == 1000 and res["hs"] > 0     # a real node exchange
+    for a in res["answers"]:
+        assert a["devices"] == 8
+        assert a["true_rel"] <= 1e-7, a
+        assert a["x_rel"] <= 1e-6, a
 
 
 @pytest.mark.slow

@@ -108,6 +108,48 @@ def test_plan_build_records_pack_once_within_build(problem, table):
     assert 0 < t["plan.pack"][1] <= t["plan.build"][1]
 
 
+def test_plan_build_records_halo_once_within_build(problem, table):
+    build_spmv_plan(problem[0], 2, 2)
+    t = spans.totals()
+    assert t["plan.build"][0] == 1 and t["plan.halo"][0] == 1
+    assert 0 < t["plan.halo"][1] <= t["plan.build"][1]
+
+
+def _off_node_columns(A, node_bounds) -> int:
+    """Distinct columns each node's rows hold outside its own row block,
+    summed over nodes: the ghosts of a square plan, counted plainly."""
+    total = 0
+    for lo, hi in zip(node_bounds[:-1], node_bounds[1:]):
+        cols = A.indices[A.indptr[lo]:A.indptr[hi]]
+        total += len(set(cols[(cols < lo) | (cols >= hi)].tolist()))
+    return total
+
+
+@pytest.mark.parametrize("mesh,wire", [((1, 1), "f32"), ((2, 2), "f32"),
+                                       ((2, 2), "bf16"), ((4, 2), "f32"),
+                                       ((4, 2), "bf16"), ((4, 1), "f32")])
+def test_exchange_counters_match_plain_counts(problem, table, mesh, wire):
+    """One SpMV's exchange, counted at the plan build: the wire bytes equal
+    what the static verifier derives from the traced exchange, the ghosts
+    a plain count of each node's off-node columns, and the x-bin gather
+    the core axis's share of the bins."""
+    from repro.analysis.jaxpr_pass import derived_wire_bytes, trace_exchange
+    A = problem[0]
+    plan, layout = build_spmv_plan(A, *mesh, wire_dtype=wire)
+    t = spans.totals()
+    n_node, n_core = mesh
+    wire_bytes = (derived_wire_bytes(trace_exchange(plan, plan.transport),
+                                     n_node, n_core) if plan.hs else 0)
+    assert t["halo.wire_bytes"][0] == wire_bytes
+    assert (wire_bytes > 0) == (n_node > 1)
+    assert t["halo.ghosts"][0] == _off_node_columns(
+        A, layout["node_bounds"]) == layout["halo"].total_ghosts
+    assert t["spmv.gather_bytes"][0] == (
+        n_node * n_core * (n_core - 1) * plan.cc_pad * 4)
+    if n_core == 1:
+        assert t["spmv.gather_bytes"][0] == 0
+
+
 def test_place_bytes_equals_operand_nbytes(problem, table):
     _, plan, _, mesh, _ = problem
     solve = make_solver(plan, mesh, solver="cg", precond="jacobi")

@@ -146,6 +146,31 @@ def test_cg_jacobi_solve_compiles_for_v5e(topo, operator, abstract_put,
         plan)
 
 
+def test_exchange_carries_spmv_scopes_for_v5e(topo, operator, abstract_put):
+    """On a 2x2 mesh the loop's node exchange and x-bin gather keep the
+    ``spmv/halo`` and ``spmv/assemble`` scopes in their ``op_name``, so a
+    device trace can tell the exchange from the solver's reductions."""
+    mesh = _mesh(topo, (2, 2))
+    plan, _ = build_spmv_plan(operator, 2, 2)
+    solve = make_solver(plan, mesh, solver="cg", precond="none",
+                        maxiter_static=10_000)
+    b = _sds(plan.cg_shape, jnp.float32, mesh, P("node", "core"))
+    txt = solve.jitted.lower(
+        b, _sds((), jnp.float32, mesh),
+        _sds((), jnp.int32, mesh)).compile().as_text()
+    names = {}
+    for line in txt.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = .*? (all-to-all|all-gather)"
+                     r"(?:-start)?\(.*op_name=\"([^\"]*)\"", line)
+        if m:
+            names.setdefault(m.group(1), []).append(m.group(2))
+    # under the solver's vmap the scope reads ``vmap(spmv)/halo/...``
+    assert any(re.search(r"spmv\)?/halo/", n)
+               for n in names["all-to-all"]), names
+    assert any(re.search(r"spmv\)?/assemble/", n)
+               for n in names["all-gather"]), names
+
+
 @pytest.mark.parametrize("shape", MESHES)
 def test_served_chunk_compiles_for_v5e(topo, operator, abstract_put, shape):
     mesh = _mesh(topo, shape)
